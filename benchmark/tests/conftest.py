@@ -1,0 +1,44 @@
+"""Tests of the benchmark's own code, on the CPU at a tiny scale:
+``python -m pytest benchmark/tests``. They never report a device metric."""
+
+import json
+import os
+import shutil
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import pytest  # noqa: E402
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO = os.path.dirname(BENCH)
+sys.path[:0] = [BENCH, REPO]
+
+TINY_SCALE = 0.002  # 3,000 orders, about 12,000 lineitem rows
+
+
+@pytest.fixture
+def tiny_root(tmp_path):
+    """A checkout-like root holding BENCHMARK.json and a copy of the
+    benchmark directory whose configurations run at TINY_SCALE."""
+    root = tmp_path / "root"
+    shutil.copytree(BENCH, root / "benchmark",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+    for path in (root / "benchmark" / "configs").glob("*.json"):
+        config = json.loads(path.read_text())
+        config["scale_factor"] = TINY_SCALE
+        path.write_text(json.dumps(config))
+    return str(root)
+
+
+@pytest.fixture(autouse=True)
+def fresh_sketch_cache():
+    """The program caches sketch tables process-wide by (index name, log
+    entry id), whatever the system path: a second run in one process with
+    an index of the same name would read the first run's sketches and
+    prune nothing. The benchmark runs one cell per process; these tests
+    run many, so each starts with the cache empty."""
+    from hyperspace_tpu.rules import data_skipping_rule
+    data_skipping_rule._SKETCH_CACHE.clear()
+    yield
