@@ -26,6 +26,8 @@ import pyarrow.parquet as pq
 
 from ..exceptions import HyperspaceException
 from ..schema import BOOL, DATE, FLOAT32, FLOAT64, INT32, INT64, STRING, Field, Schema
+from ..telemetry import span_names as SN
+from ..telemetry import trace as _trace
 
 _DEVICE_DTYPE = {
     INT32: jnp.int32,
@@ -252,7 +254,8 @@ class Table:
             arrays[(n, "d")] = c.data
             if c.validity is not None:
                 arrays[(n, "v")] = c.validity
-        host = jax.device_get(arrays)
+        with _trace.span(SN.DEVICE_SYNC, node="to_host"):
+            host = jax.device_get(arrays)
         rows = self.num_rows
 
         def trim(a):
@@ -318,7 +321,10 @@ class Table:
             if col.validity is not None and \
                     not isinstance(col.validity, np.ndarray):
                 device_buffers[(name, "v")] = col.validity
-        fetched = jax.device_get(device_buffers) if device_buffers else {}
+        fetched = {}
+        if device_buffers:
+            with _trace.span(SN.DEVICE_SYNC, node="result"):
+                fetched = jax.device_get(device_buffers)
 
         def fetch(a, key):
             if key in fetched:

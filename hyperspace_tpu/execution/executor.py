@@ -53,7 +53,7 @@ def execute(plan: LogicalPlan, session=None) -> Table:
         from ..parallel import io as pio
         conf = session.hs_conf if session is not None else None
         with shapes.use_conf(conf), pio.use_session(session), \
-                shapes.compile_scope("execute") as tally:
+                shapes.compile_scope() as tally:
             # Row-returning distributed path: a {Filter, Project, Join}*
             # chain root (optionally under Sort/Limit) runs SPMD over the
             # mesh, rows gathered per device (execution/spmd.py). Aggregate

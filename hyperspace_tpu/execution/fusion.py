@@ -585,9 +585,13 @@ def _make_builder(spec: _RegionSpec):
     """The fused program body. Pure function of ``spec`` (== the bank
     key), as the ProgramBank contract requires."""
 
-    def run(args):
-        import jax
+    def fused_region(args):
+        # A stable program name (the HLO module ``jit_fused_region``) and
+        # scope, so a profile reads the same from compile to compile.
+        with jax.named_scope("fused_region"):
+            return run(args)
 
+    def run(args):
         n, col_arrays, lit_stages, sides = args
         cols: Dict[str, Column] = {}
         for name, (data, validity) in zip(spec.col_order, col_arrays):
@@ -708,7 +712,6 @@ def _make_builder(spec: _RegionSpec):
         ng = jnp.where(jnp.any(keep_s), last + 1, 0).astype(jnp.int32)
         gids = jnp.where(keep_s, gids, jnp.int32(phys))
         out["ng"] = ng
-        import jax
         firsts = jax.ops.segment_min(iota, gids, num_segments=phys)
         for g in spec.group_cols:
             c = scols[g]
@@ -724,7 +727,7 @@ def _make_builder(spec: _RegionSpec):
                 out[f"av:{a.name}"] = col.validity
         return out
 
-    return run
+    return fused_region
 
 
 # ---------------------------------------------------------------------------
@@ -971,7 +974,8 @@ def _record_actuals(region: _Region, out, session) -> None:
         if node.join_type != "inner" or node.condition is None \
                 or rows_key not in out:
             continue
-        rows = int(out[rows_key])  # HOST SYNC (single scalar)
+        with _trace.span(SN.DEVICE_SYNC, node="fused.join_rows"):
+            rows = int(out[rows_key])  # HOST SYNC (single scalar)
         key = qctx.join_actual_key(node.condition, node.left, node.right)
         ctx = qctx.active_context()
         if ctx is not None:
@@ -985,7 +989,8 @@ def _finish_chain(spec: _RegionSpec, out, final_meta) -> Table:
     survivor count (the ONE scalar sync), class-padded gather indices,
     one fused gather."""
     from ..ops import kernels
-    m = int(out["count"])  # HOST SYNC (single scalar)
+    with _trace.span(SN.DEVICE_SYNC, node="fused.count"):
+        m = int(out["count"])  # HOST SYNC (single scalar)
     cls = shapes.padded_length(m)
     idx = kernels.nonzero_pad_indices(out["mask"], cls)
     cols = {}
@@ -1017,7 +1022,8 @@ def _finish_global(agg: Aggregate, out, final_meta) -> Table:
 
 def _finish_grouped(agg: Aggregate, spec: _RegionSpec, out,
                     final_meta) -> Table:
-    ng = int(out["ng"])  # HOST SYNC (single scalar)
+    with _trace.span(SN.DEVICE_SYNC, node="fused.groups"):
+        ng = int(out["ng"])  # HOST SYNC (single scalar)
     if ng == 0:
         # Mirror executor._execute_aggregate's empty-result construction.
         cols = {}

@@ -31,6 +31,9 @@ Compile observability: a process-level counter hooked on jax.monitoring's
 XLA backend compile). The executor emits the per-execution delta as a
 ``KernelCompileEvent``; ``explain()`` surfaces totals in its
 "Compilation:" section; bench.py records per-phase counts from it.
+Inside a traced query each compile is also an ``xla.compile`` span
+naming the jitted function and whether the persistent compilation cache
+served it.
 """
 
 from __future__ import annotations
@@ -39,14 +42,17 @@ import contextlib
 import contextvars
 import math
 import threading
+import time
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..index.constants import IndexConstants
+from ..telemetry import span_names as SN
+from ..telemetry import trace as _trace
 
 # ---------------------------------------------------------------------------
 # Parameters (conf-backed; see config.py shape_bucketing_* accessors).
@@ -204,16 +210,23 @@ def mask_tail(arr, n: int, fill):
 # ---------------------------------------------------------------------------
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# Recorded only when the persistent compilation cache served the
+# executable, on the compiling thread, inside the compile event's span.
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _counter_lock = threading.Lock()
 _compile_total = 0
 _compile_seconds = 0.0
-_scope_counts: Dict[str, int] = {}
 _SCOPE: contextvars.ContextVar = contextvars.ContextVar(
     "hst_compile_scope", default=None)
 _listener_installed = False
+_cache_hit = threading.local()
 
 
-def _on_compile_event(event: str, duration_secs: float, **_kw) -> None:
+def _on_compile_event(event: str, duration_secs: float, **kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        if not _trace.idle():
+            _cache_hit.seen = True
+        return
     if event != _COMPILE_EVENT:
         return
     global _compile_total, _compile_seconds
@@ -224,8 +237,20 @@ def _on_compile_event(event: str, duration_secs: float, **_kw) -> None:
         if holder is not None:
             holder["count"] += 1
             holder["seconds"] += float(duration_secs)
-            label = holder["label"]
-            _scope_counts[label] = _scope_counts.get(label, 0) + 1
+    if not _trace.idle():
+        _record_compile_span(kw.get("fun_name", ""), float(duration_secs))
+
+
+def _record_compile_span(fun_name: str, seconds: float) -> None:
+    """One ``xla.compile`` span for the compile that just ended on this
+    thread, placed back over the time it took."""
+    end = time.perf_counter()
+    cached = bool(getattr(_cache_hit, "seen", False))
+    _cache_hit.seen = False
+    span = _trace.add_span(SN.XLA_COMPILE, end - seconds, node=fun_name,
+                           fun_name=fun_name, seconds=seconds, cached=cached)
+    if span is not None:
+        span.end_perf = end
 
 
 def install_compile_counter() -> None:
@@ -256,19 +281,15 @@ def compile_seconds() -> float:
     return _compile_seconds
 
 
-def scope_compile_count(label: str) -> int:
-    return _scope_counts.get(label, 0)
-
-
 @contextlib.contextmanager
-def compile_scope(label: str):
-    """Attribute compiles fired inside the scope to ``label`` (the executor
-    wraps plan execution; tests wrap individual kernels). Yields a holder
-    dict whose ``count``/``seconds`` tally only THIS context's compiles —
-    the contextvar keeps concurrent serving executions from reading each
-    other's deltas off the process-global counter."""
+def compile_scope():
+    """Tally the compiles fired inside the scope (the executor wraps plan
+    execution). Yields a holder dict whose ``count``/``seconds`` tally
+    only THIS context's compiles — the contextvar keeps concurrent
+    serving executions from reading each other's deltas off the
+    process-global counter."""
     install_compile_counter()
-    holder = {"label": label, "count": 0, "seconds": 0.0}
+    holder = {"count": 0, "seconds": 0.0}
     token = _SCOPE.set(holder)
     try:
         yield holder

@@ -672,15 +672,16 @@ def try_execute_aggregate(plan: Aggregate, session,
     """Execute an Aggregate subtree SPMD over the mesh, or return None to
     fall back. ``executor(plan, needed)`` is the single-device recursive
     executor, used to materialize the scan leaf and join sides."""
-    if not _spmd_eligible(session):
-        return None
-    if not _passes_min_rows(plan.child, session):
-        return None
-    if not _leaf_within_budget(plan.child, session):
-        from ..telemetry.logging import emit_distributed_fallback
-        emit_distributed_fallback(session, "spmd_query",
-                                  "leaf exceeds device chunk budget")
-        return None
+    with _trace.span(SN.SPMD_GATE):
+        if not _spmd_eligible(session):
+            return None
+        if not _passes_min_rows(plan.child, session):
+            return None
+        if not _leaf_within_budget(plan.child, session):
+            from ..telemetry.logging import emit_distributed_fallback
+            emit_distributed_fallback(session, "spmd_query",
+                                      "leaf exceeds device chunk budget")
+            return None
     try:
         return _run(plan, executor, session)
     except _Unsupported as e:
@@ -697,31 +698,32 @@ def try_execute_plan(plan, session, executor: Callable) -> Optional[Table]:
     is already reduced). Returns None to fall back."""
     from ..plan.nodes import Limit, Sort
 
-    if not _spmd_eligible(session):
-        return None
-    wrappers = []
-    node = plan
-    while isinstance(node, (Sort, Limit)):
-        wrappers.append(node)
-        node = node.child
-    if isinstance(node, Aggregate):
-        return None  # aggregates dispatch inside the executor
-    if isinstance(node, (Scan, IndexScan)) and not (
-            wrappers and isinstance(wrappers[-1], Sort)
-            and _use_spmd_sort()):
-        return None  # a bare scan has no distributed work — unless a
-        # Sort sits above it (the distributed sample sort IS the work)
-    try:
-        _linearize(node)  # raises _Unsupported on non-chain shapes
-    except _Unsupported:
-        return None
-    if not _passes_min_rows(node, session):
-        return None
-    if not _leaf_within_budget(node, session):
-        from ..telemetry.logging import emit_distributed_fallback
-        emit_distributed_fallback(session, "spmd_query",
-                                  "leaf exceeds device chunk budget")
-        return None
+    with _trace.span(SN.SPMD_GATE):
+        if not _spmd_eligible(session):
+            return None
+        wrappers = []
+        node = plan
+        while isinstance(node, (Sort, Limit)):
+            wrappers.append(node)
+            node = node.child
+        if isinstance(node, Aggregate):
+            return None  # aggregates dispatch inside the executor
+        if isinstance(node, (Scan, IndexScan)) and not (
+                wrappers and isinstance(wrappers[-1], Sort)
+                and _use_spmd_sort()):
+            return None  # a bare scan has no distributed work — unless a
+            # Sort sits above it (the distributed sample sort IS the work)
+        try:
+            _linearize(node)  # raises _Unsupported on non-chain shapes
+        except _Unsupported:
+            return None
+        if not _passes_min_rows(node, session):
+            return None
+        if not _leaf_within_budget(node, session):
+            from ..telemetry.logging import emit_distributed_fallback
+            emit_distributed_fallback(session, "spmd_query",
+                                      "leaf exceeds device chunk budget")
+            return None
     # Distributed ORDER BY: the innermost Sort runs ON the mesh as a
     # range-partitioned sample sort, so the host gather receives sorted
     # device ranges instead of unsorted rows (VERDICT r5 #4).
@@ -1158,7 +1160,7 @@ def _record_join_actuals(session, prep: "_Prepared", out) -> None:
         key = f"jrows:{i}"
         if kind != "join" or key not in out:
             continue
-        rows = int(np.asarray(jax.device_get(out[key])))
+        rows = int(_fetch("spmd.join_rows", out[key]))
         akey = qctx.join_actual_key(node.condition, node.left, node.right)
         if ctx is not None:
             ctx.record_join_actual(akey, rows)
@@ -1199,15 +1201,16 @@ def _run_impl(plan: Aggregate, executor, session=None
     # Prepared ONCE: leaf IO, join-side materialization, and sharding don't
     # depend on caps — only the jitted program (static shapes) does, so
     # escalation retries recompile but never redo IO.
-    prep = _prepare(plan.child, executor, caps, session)
+    with _trace.span(SN.SPMD_PREPARE):
+        prep = _prepare(plan.child, executor, caps, session)
 
-    def probe(e: E.Expr) -> Column:
-        t = {n: Column(dt, jnp.zeros(0, _DEVICE_DTYPE[dt]),
-                       jnp.zeros(0, jnp.bool_) if nul else None, dic)
-             for n, (dt, dic, nul) in prep.final_meta.items()}
-        return eval_expr(Table(t), e)
+        def probe(e: E.Expr) -> Column:
+            t = {n: Column(dt, jnp.zeros(0, _DEVICE_DTYPE[dt]),
+                           jnp.zeros(0, jnp.bool_) if nul else None, dic)
+                 for n, (dt, dic, nul) in prep.final_meta.items()}
+            return eval_expr(Table(t), e)
 
-    agg_specs = tuple(_AggSpec.build(a, probe) for a in plan.aggs)
+        agg_specs = tuple(_AggSpec.build(a, probe) for a in plan.aggs)
     group_cols = tuple(plan.group_cols)
     for g in group_cols:
         if g not in prep.final_meta:
@@ -1240,9 +1243,11 @@ def _run_impl(plan: Aggregate, executor, session=None
         descr = _StageDescr(prep.stages, prep.joins, prep.col_meta,
                             agg_specs, group_cols, dict(caps),
                             prep.project_live)
-        out = _spmd_program(prep.sharded, prep.valid, prep.bcast, prep.xch,
-                            mesh=prep.mesh, descr=descr, grouped=grouped,
-                            G=G, G2=G2, mode="agg", routed_merge=routed)
+        with _trace.span(SN.SPMD_LAUNCH):
+            out = _spmd_program(prep.sharded, prep.valid, prep.bcast,
+                                prep.xch, mesh=prep.mesh, descr=descr,
+                                grouped=grouped, G=G, G2=G2, mode="agg",
+                                routed_merge=routed)
         if _escalate_on_overflow(out, caps):
             cap_attempts += 1
             n_xch = sum(1 for j in prep.joins.values() if j[0] == "x")
@@ -1256,17 +1261,17 @@ def _run_impl(plan: Aggregate, executor, session=None
             gmof_retried = False
             continue
         if grouped:
-            if bool(np.asarray(jax.device_get(out["overflow"]))):
+            if bool(_fetch("spmd.overflow", out["overflow"])):
                 if gof_retried:
                     raise _Unsupported("local group capacity overflow "
                                        "after exact-need retry")
                 gof_retried = True
-                need = int(np.asarray(jax.device_get(out["gneed"])))
+                need = int(_fetch("spmd.overflow", out["gneed"]))
                 G_floor = min(_round_up_pow2(need),
                               _out_rows(prep, caps))
                 gmof_retried = False  # new G → new owner distribution
                 continue
-            if routed and bool(np.asarray(jax.device_get(out["gmof"]))):
+            if routed and bool(_fetch("spmd.overflow", out["gmof"])):
                 # One owner device holds more than G2 distinct groups
                 # (hash skew). The program reports the exact capacity
                 # needed, so ONE retry — with its own budget, not the
@@ -1276,13 +1281,15 @@ def _run_impl(plan: Aggregate, executor, session=None
                 if gmof_retried:
                     raise _Unsupported("merge capacity retry failed")
                 gmof_retried = True
-                need = int(np.asarray(jax.device_get(out["gmneed"])))
+                need = int(_fetch("spmd.overflow", out["gmneed"]))
                 G2 = min(max(G2 + 1, -(-need // G) * G), n_dev * G)
                 continue
-            table = _merge_grouped(out, agg_specs, list(group_cols),
-                                   prep.final_meta, prefix=n_dev == 1)
+            with _trace.span(SN.SPMD_MERGE):
+                table = _merge_grouped(out, agg_specs, list(group_cols),
+                                       prep.final_meta, prefix=n_dev == 1)
         else:
-            table = _merge_global(out, agg_specs, prep.final_meta)
+            with _trace.span(SN.SPMD_MERGE):
+                table = _merge_global(out, agg_specs, prep.final_meta)
         with _COUNT_LOCK:
             DISPATCH_COUNT += 1
         _record_join_actuals(session, prep, out)
@@ -1323,7 +1330,8 @@ def _run_stream_impl(root, executor, sort_orders=(), session=None
     with _COUNT_LOCK:
         LAST_CAP_ATTEMPTS = 1
     caps: Dict[int, Tuple[int, int]] = {}
-    prep = _prepare(root, executor, caps, session)  # once; see _run
+    with _trace.span(SN.SPMD_PREPARE):
+        prep = _prepare(root, executor, caps, session)  # once; see _run
     out_names = [n for n in root.schema.names if n in prep.final_meta]
     if not out_names:
         raise _Unsupported("no output columns")
@@ -1346,21 +1354,23 @@ def _run_stream_impl(root, executor, sort_orders=(), session=None
         descr = _StageDescr(prep.stages, prep.joins, prep.col_meta,
                             (), out_pairs, dict(caps), prep.project_live,
                             sort_orders=tuple(sort_orders))
-        out = _spmd_program(prep.sharded, prep.valid, prep.bcast, prep.xch,
-                            mesh=prep.mesh, descr=descr, grouped=False,
-                            G=1, mode=mode)
+        with _trace.span(SN.SPMD_LAUNCH):
+            out = _spmd_program(prep.sharded, prep.valid, prep.bcast,
+                                prep.xch, mesh=prep.mesh, descr=descr,
+                                grouped=False, G=1, mode=mode)
         if _escalate_on_overflow(out, caps):
             continue
-        mask = np.asarray(jax.device_get(out["omask"]))
-        cols: Dict[str, Column] = {}
-        for n in out_names:
-            dt, dic, nul = prep.final_meta[n]
-            data = np.asarray(jax.device_get(out[f"o:{n}"]))[mask]
-            validity = None
-            if f"ov:{n}" in out:
-                validity = jnp.asarray(
-                    np.asarray(jax.device_get(out[f"ov:{n}"]))[mask])
-            cols[n] = Column(dt, jnp.asarray(data), validity, dic)
+        with _trace.span(SN.SPMD_MERGE):
+            mask = _fetch("spmd.gather", out["omask"])
+            cols: Dict[str, Column] = {}
+            for n in out_names:
+                dt, dic, nul = prep.final_meta[n]
+                data = _fetch("spmd.gather", out[f"o:{n}"])[mask]
+                validity = None
+                if f"ov:{n}" in out:
+                    validity = jnp.asarray(
+                        _fetch("spmd.gather", out[f"ov:{n}"])[mask])
+                cols[n] = Column(dt, jnp.asarray(data), validity, dic)
         with _COUNT_LOCK:
             DISPATCH_COUNT += 1
             if mode == "sort":
@@ -1397,10 +1407,10 @@ def _escalate_on_overflow(out, caps: Dict[int, Tuple[int, int]]) -> bool:
         if not key.startswith("xof:"):
             continue
         i = int(key.split(":")[1])
-        if bool(np.asarray(jax.device_get(out[key]))):
+        if bool(_fetch("spmd.overflow", out[key])):
             cap, k_out = caps[i]
-            need_c = int(np.asarray(jax.device_get(out[f"xneedc:{i}"])))
-            need_o = int(np.asarray(jax.device_get(out[f"xneedo:{i}"])))
+            need_c = int(_fetch("spmd.overflow", out[f"xneedc:{i}"]))
+            need_o = int(_fetch("spmd.overflow", out[f"xneedo:{i}"]))
             send_of = need_c > cap  # definitionally the send overflow
             new_cap = max(cap, _round_up_pow2(need_c))
             new_out = max(k_out, _round_up_pow2(
@@ -1608,339 +1618,347 @@ def _spmd_program(sharded, valid, bcast, xch, *, mesh: Mesh,
         overflow_flags = {}
 
         for i, (kind, node) in enumerate(stages):
-            if kind == "filter":
-                mask = mask & eval_predicate_mask(table, node.condition)
-            elif kind == "project":
-                live = descr.project_live.get(i, frozenset())
-                table = Table({e.name: eval_expr(table, e)
-                               for e in node.exprs if e.name in live})
-            elif joins[i][0] == "b":  # broadcast join probe
-                _, pairs, side, jt = joins[i]
-                lk, keys_valid = _stream_probe_key(table, pairs, side.pack)
-                rkeys = bcast[f"k:{i}"]
-                n_r = rkeys.shape[0]
-                if n_r == 0:
-                    found = jnp.zeros(lk.shape[0], jnp.bool_)
-                    idx_c = jnp.zeros(lk.shape[0], jnp.int32)
-                else:
-                    idx = jnp.searchsorted(rkeys, lk)
-                    idx_c = jnp.minimum(idx, n_r - 1)
-                    found = jnp.take(rkeys, idx_c) == lk
-                found = found & keys_valid
-                if jt == "semi":
-                    mask = mask & found
-                    continue
-                if jt == "anti":
-                    # Null / unmatched keys match nothing → kept (the
-                    # NOT IN non-null convention the executor documents).
-                    mask = mask & ~found
-                    continue
-                if jt == "inner":
-                    mask = mask & found
-                    # Observed join output rows (m:1 probe: one emit per
-                    # surviving stream row) — psum'd so the host can
-                    # write the actual back to the session's q-error
-                    # store (optimizer/join_order pairing).
-                    overflow_flags[f"jrows:{i}"] = jax.lax.psum(
-                        jnp.sum(mask.astype(jnp.int32)), DATA_AXIS)
-                # left outer: mask unchanged — unmatched stream rows stay,
-                # with the right columns invalid below.
-                rnames = {r for _, r in pairs}
-                new_cols = dict(table.columns)
-                for n in side.table.names:
-                    if n in rnames:
-                        continue
-                    rc = side.table.column(n)
+            with jax.named_scope(f"stage{i}_{kind}"):
+                if kind == "filter":
+                    mask = mask & eval_predicate_mask(table, node.condition)
+                elif kind == "project":
+                    live = descr.project_live.get(i, frozenset())
+                    table = Table({e.name: eval_expr(table, e)
+                                   for e in node.exprs if e.name in live})
+                elif joins[i][0] == "b":  # broadcast join probe
+                    _, pairs, side, jt = joins[i]
+                    lk, keys_valid = _stream_probe_key(table, pairs, side.pack)
+                    rkeys = bcast[f"k:{i}"]
+                    n_r = rkeys.shape[0]
                     if n_r == 0:
-                        data = jnp.zeros(lk.shape[0],
-                                         _DEVICE_DTYPE[rc.dtype])
-                        vv = None
+                        found = jnp.zeros(lk.shape[0], jnp.bool_)
+                        idx_c = jnp.zeros(lk.shape[0], jnp.int32)
                     else:
-                        data = jnp.take(bcast[f"b:{i}:{n}"], idx_c, axis=0)
-                        vkey = f"bv:{i}:{n}"
-                        vv = (jnp.take(bcast[vkey], idx_c)
-                              if vkey in bcast else None)
-                    if jt == "left":
-                        vv = found if vv is None else (vv & found)
-                    new_cols[n] = Column(rc.dtype, data, vv, rc.dictionary)
-                for lname, rname in pairs:
-                    if rname in node.schema.names and rname not in new_cols:
-                        lc = table.column(lname)
-                        # Matched rows: right key == left key by definition;
-                        # left-outer padding rows carry a null right key.
-                        vv = lc.validity
+                        idx = jnp.searchsorted(rkeys, lk)
+                        idx_c = jnp.minimum(idx, n_r - 1)
+                        found = jnp.take(rkeys, idx_c) == lk
+                    found = found & keys_valid
+                    if jt == "semi":
+                        mask = mask & found
+                        continue
+                    if jt == "anti":
+                        # Null / unmatched keys match nothing → kept (the
+                        # NOT IN non-null convention the executor documents).
+                        mask = mask & ~found
+                        continue
+                    if jt == "inner":
+                        mask = mask & found
+                        # Observed join output rows (m:1 probe: one emit per
+                        # surviving stream row) — psum'd so the host can
+                        # write the actual back to the session's q-error
+                        # store (optimizer/join_order pairing).
+                        overflow_flags[f"jrows:{i}"] = jax.lax.psum(
+                            jnp.sum(mask.astype(jnp.int32)), DATA_AXIS)
+                    # left outer: mask unchanged — unmatched stream rows stay,
+                    # with the right columns invalid below.
+                    rnames = {r for _, r in pairs}
+                    new_cols = dict(table.columns)
+                    for n in side.table.names:
+                        if n in rnames:
+                            continue
+                        rc = side.table.column(n)
+                        if n_r == 0:
+                            data = jnp.zeros(lk.shape[0],
+                                             _DEVICE_DTYPE[rc.dtype])
+                            vv = None
+                        else:
+                            data = jnp.take(bcast[f"b:{i}:{n}"], idx_c, axis=0)
+                            vkey = f"bv:{i}:{n}"
+                            vv = (jnp.take(bcast[vkey], idx_c)
+                                  if vkey in bcast else None)
                         if jt == "left":
                             vv = found if vv is None else (vv & found)
-                        new_cols[rname] = Column(lc.dtype, lc.data, vv,
-                                                 lc.dictionary)
-                table = Table(new_cols)
-            else:  # exchange (m:n shuffle) join
-                _, pairs, side, jt = joins[i]
-                cap, k_out = descr.caps[i]
-                lk, keys_valid = _stream_probe_key(table, pairs, side.pack)
-                preserve_left = jt in ("left", "full")
-                preserve_right = jt in ("right", "full")
-                # Preserved-left rows route even with a null key (they
-                # must surface as unmatched); a "kv" flag rides along so
-                # the merge still refuses to match them. Otherwise
-                # null-key rows are dropped at the send.
-                l_ok = mask if preserve_left else (mask & keys_valid)
-                # Routing hashes the key in the SAME code space on both
-                # sides, so equal keys land on one device. String keys are
-                # already translated into one dictionary — their codes
-                # hash as plain int32 (no dictionary needed for routing;
-                # equal codes ⇔ equal strings); multi-key composites are
-                # packed int64 on both sides.
-                dtype = INT32 if side.key_dtype == STRING else side.key_dtype
-                dst_l = (kernels.hash32_values(lk, dtype)
-                         % np.uint32(n_dev)).astype(jnp.int32)
-                l_arrays = {"k": lk}
-                if preserve_left:
-                    l_arrays["kv"] = keys_valid
-                for n in table.names:
-                    c = table.column(n)
-                    l_arrays[f"d:{n}"] = c.data
-                    if c.validity is not None:
-                        l_arrays[f"v:{n}"] = c.validity
-                recv_l, lvalid, of_l, need_l = _a2a_exchange(
-                    l_arrays, l_ok, dst_l, n_dev, cap)
-
-                rk = xch[f"x:{i}:k"]
-                r_ok = xch[f"x:{i}:__valid"]
-                dst_r = (kernels.hash32_values(rk, dtype)
-                         % np.uint32(n_dev)).astype(jnp.int32)
-                r_arrays = {n[len(f"x:{i}:"):]: a for n, a in xch.items()
-                            if n.startswith(f"x:{i}:") and
-                            not n.endswith("__valid")}
-                recv_r, rvalid, of_r, need_r = _a2a_exchange(
-                    r_arrays, r_ok, dst_r, n_dev, cap)
-                overflow_flags[f"xof:{i}"] = jnp.maximum(of_l, of_r)
-                # Exact retry sizing: worst (src, dst) block over both
-                # sides. Send overflow is recoverable host-side as
-                # need > cap, so no separate flag rides along.
-                overflow_flags[f"xneedc:{i}"] = jnp.maximum(need_l, need_r)
-
-                # Local merge join: right sorted (valid first, by key),
-                # invalid tail pinned to the key dtype's max so the whole
-                # array stays ascending for searchsorted; hi is clamped to
-                # the valid prefix length. Because equal keys all meet on
-                # one device, LOCAL match status is GLOBAL match status —
-                # which is what lets outer joins emit their unmatched
-                # rows here without any further coordination.
-                rkr = recv_r["k"]
-                # Key-valid ∧ receive-valid: null-key right rows (carried
-                # only under right/full, flagged "kv") must never match
-                # but still appendix as unmatched.
-                rkeyok = rvalid
-                if "kv" in recv_r:
-                    rkeyok = rvalid & recv_r["kv"]
-                sort_r = kernels.lex_sort_indices(
-                    [(~rkeyok).astype(jnp.int32), rkr])
-                rk_sorted = jnp.take(rkr, sort_r)
-                rvalid_sorted = jnp.take(rvalid, sort_r)
-                rkeyok_sorted = jnp.take(rkeyok, sort_r)
-                n_valid_r = jnp.sum(rkeyok.astype(jnp.int32))
-                rk_probe = jnp.where(rkeyok_sorted, rk_sorted,
-                                     _max_sentinel(rk_sorted.dtype))
-                lkr = recv_l["k"]
-                lkvalid = lvalid
-                if preserve_left:
-                    lkvalid = lvalid & recv_l["kv"]
-                lo = jnp.searchsorted(rk_probe, lkr, side="left")
-                hi = jnp.minimum(
-                    jnp.searchsorted(rk_probe, lkr, side="right"), n_valid_r)
-                matched_counts = jnp.where(
-                    lkvalid, jnp.maximum(hi - lo, 0), 0).astype(jnp.int32)
-                if preserve_left:
-                    # Every received stream row emits at least once.
-                    emit_counts = jnp.where(
-                        lvalid, jnp.maximum(matched_counts, 1), 0)
-                else:
-                    emit_counts = matched_counts
-                total_l = jnp.sum(emit_counts)
-                n_l = lkr.shape[0]
-                li = jnp.repeat(jnp.arange(n_l, dtype=jnp.int32),
-                                emit_counts, total_repeat_length=k_out)
-                starts_ = jnp.cumsum(emit_counts) - emit_counts
-                base = jnp.repeat(starts_.astype(jnp.int32), emit_counts,
-                                  total_repeat_length=k_out)
-                within = jnp.arange(k_out, dtype=jnp.int32) - base
-                is_match = within < jnp.take(matched_counts, li)
-                ri = jnp.repeat(lo.astype(jnp.int32), emit_counts,
-                                total_repeat_length=k_out) + \
-                    jnp.where(is_match, within, 0)
-                ri = jnp.clip(ri, 0, max(rkr.shape[0] - 1, 0))
-
-                if preserve_right:
-                    # Right rows whose key no received left row carries
-                    # emit once, appended after the matched block. The
-                    # left keys need their own sort for the probe.
-                    sort_l = kernels.lex_sort_indices(
-                        [(~lkvalid).astype(jnp.int32), lkr])
-                    lk_sorted = jnp.take(lkr, sort_l)
-                    lkv_sorted = jnp.take(lkvalid, sort_l)
-                    n_valid_l = jnp.sum(lkvalid.astype(jnp.int32))
-                    lk_probe = jnp.where(lkv_sorted, lk_sorted,
-                                         _max_sentinel(lk_sorted.dtype))
-                    lo_r = jnp.searchsorted(lk_probe, rk_sorted, side="left")
-                    hi_r = jnp.minimum(
-                        jnp.searchsorted(lk_probe, rk_sorted, side="right"),
-                        n_valid_l)
-                    r_unmatched = rvalid_sorted & \
-                        (~rkeyok_sorted | ((hi_r - lo_r) <= 0))
-                    appendix = jnp.sum(r_unmatched.astype(jnp.int32))
-                    appendix_pos = total_l + jnp.cumsum(
-                        r_unmatched.astype(jnp.int32)) - 1
-                    # mode="drop" discards slots at/above k_out.
-                    appendix_slot = jnp.where(r_unmatched, appendix_pos,
-                                              k_out).astype(jnp.int32)
-                    total_eff = total_l + appendix
-                else:
-                    appendix_slot = None
-                    total_eff = total_l
-                overflow_flags[f"xof:{i}"] = jnp.maximum(
-                    overflow_flags[f"xof:{i}"],
-                    jax.lax.pmax((total_eff > k_out).astype(jnp.int32),
-                                 DATA_AXIS))
-                # Exact per-device output need (counts are computed before
-                # any slot clamping, so this is exact whenever the send
-                # side fit — xneedc above cap marks the exception).
-                overflow_flags[f"xneedo:{i}"] = jax.lax.pmax(
-                    total_eff.astype(jnp.int32), DATA_AXIS)
-                out_mask = jnp.arange(k_out, dtype=jnp.int32) < total_eff
-
-                live = jnp.arange(k_out, dtype=jnp.int32) < total_l
-                new_cols = {}
-                for n in table.names:
-                    # Stream meta snapshot from prep time: projects below
-                    # this join may have created/redefined columns the
-                    # leaf col_meta doesn't describe.
-                    dt, dic, _ = side.stream_meta[n]
-                    data = jnp.take(recv_l[f"d:{n}"], li, axis=0)
-                    vv = (jnp.take(recv_l[f"v:{n}"], li)
-                          if f"v:{n}" in recv_l else None)
-                    if preserve_right:
-                        # Appendix rows have no left side: null-pad.
-                        vv = live if vv is None else (vv & live)
-                    new_cols[n] = Column(dt, data, vv, dic)
-                for n, (dt, dic, nul) in side.table_meta.items():
-                    col_sorted = jnp.take(recv_r[f"d:{n}"], sort_r, axis=0)
-                    data = jnp.take(col_sorted, ri, axis=0)
-                    vv = (jnp.take(jnp.take(recv_r[f"v:{n}"], sort_r), ri)
-                          if f"v:{n}" in recv_r else None)
+                        new_cols[n] = Column(rc.dtype, data, vv, rc.dictionary)
+                    for lname, rname in pairs:
+                        if rname in node.schema.names \
+                                and rname not in new_cols:
+                            lc = table.column(lname)
+                            # Matched rows: right key == left key by
+                            # definition; left-outer padding rows carry a
+                            # null right key.
+                            vv = lc.validity
+                            if jt == "left":
+                                vv = found if vv is None else (vv & found)
+                            new_cols[rname] = Column(lc.dtype, lc.data, vv,
+                                                     lc.dictionary)
+                    table = Table(new_cols)
+                else:  # exchange (m:n shuffle) join
+                    _, pairs, side, jt = joins[i]
+                    cap, k_out = descr.caps[i]
+                    lk, keys_valid = _stream_probe_key(table, pairs, side.pack)
+                    preserve_left = jt in ("left", "full")
+                    preserve_right = jt in ("right", "full")
+                    # Preserved-left rows route even with a null key (they
+                    # must surface as unmatched); a "kv" flag rides along so
+                    # the merge still refuses to match them. Otherwise
+                    # null-key rows are dropped at the send.
+                    l_ok = mask if preserve_left else (mask & keys_valid)
+                    # Routing hashes the key in the SAME code space on both
+                    # sides, so equal keys land on one device. String keys are
+                    # already translated into one dictionary — their codes
+                    # hash as plain int32 (no dictionary needed for routing;
+                    # equal codes ⇔ equal strings); multi-key composites are
+                    # packed int64 on both sides.
+                    dtype = INT32 if side.key_dtype == STRING \
+                        else side.key_dtype
+                    dst_l = (kernels.hash32_values(lk, dtype)
+                             % np.uint32(n_dev)).astype(jnp.int32)
+                    l_arrays = {"k": lk}
                     if preserve_left:
-                        # Unmatched stream rows: right side is null.
-                        vv = is_match if vv is None else (vv & is_match)
+                        l_arrays["kv"] = keys_valid
+                    for n in table.names:
+                        c = table.column(n)
+                        l_arrays[f"d:{n}"] = c.data
+                        if c.validity is not None:
+                            l_arrays[f"v:{n}"] = c.validity
+                    recv_l, lvalid, of_l, need_l = _a2a_exchange(
+                        l_arrays, l_ok, dst_l, n_dev, cap)
+
+                    rk = xch[f"x:{i}:k"]
+                    r_ok = xch[f"x:{i}:__valid"]
+                    dst_r = (kernels.hash32_values(rk, dtype)
+                             % np.uint32(n_dev)).astype(jnp.int32)
+                    r_arrays = {n[len(f"x:{i}:"):]: a for n, a in xch.items()
+                                if n.startswith(f"x:{i}:") and
+                                not n.endswith("__valid")}
+                    recv_r, rvalid, of_r, need_r = _a2a_exchange(
+                        r_arrays, r_ok, dst_r, n_dev, cap)
+                    overflow_flags[f"xof:{i}"] = jnp.maximum(of_l, of_r)
+                    # Exact retry sizing: worst (src, dst) block over both
+                    # sides. Send overflow is recoverable host-side as
+                    # need > cap, so no separate flag rides along.
+                    overflow_flags[f"xneedc:{i}"] = jnp.maximum(need_l, need_r)
+
+                    # Local merge join: right sorted (valid first, by key),
+                    # invalid tail pinned to the key dtype's max so the whole
+                    # array stays ascending for searchsorted; hi is clamped to
+                    # the valid prefix length. Because equal keys all meet on
+                    # one device, LOCAL match status is GLOBAL match status —
+                    # which is what lets outer joins emit their unmatched
+                    # rows here without any further coordination.
+                    rkr = recv_r["k"]
+                    # Key-valid ∧ receive-valid: null-key right rows (carried
+                    # only under right/full, flagged "kv") must never match
+                    # but still appendix as unmatched.
+                    rkeyok = rvalid
+                    if "kv" in recv_r:
+                        rkeyok = rvalid & recv_r["kv"]
+                    sort_r = kernels.lex_sort_indices(
+                        [(~rkeyok).astype(jnp.int32), rkr])
+                    rk_sorted = jnp.take(rkr, sort_r)
+                    rvalid_sorted = jnp.take(rvalid, sort_r)
+                    rkeyok_sorted = jnp.take(rkeyok, sort_r)
+                    n_valid_r = jnp.sum(rkeyok.astype(jnp.int32))
+                    rk_probe = jnp.where(rkeyok_sorted, rk_sorted,
+                                         _max_sentinel(rk_sorted.dtype))
+                    lkr = recv_l["k"]
+                    lkvalid = lvalid
+                    if preserve_left:
+                        lkvalid = lvalid & recv_l["kv"]
+                    lo = jnp.searchsorted(rk_probe, lkr, side="left")
+                    hi = jnp.minimum(
+                        jnp.searchsorted(rk_probe, lkr, side="right"),
+                        n_valid_r)
+                    matched_counts = jnp.where(
+                        lkvalid, jnp.maximum(hi - lo, 0), 0).astype(jnp.int32)
+                    if preserve_left:
+                        # Every received stream row emits at least once.
+                        emit_counts = jnp.where(
+                            lvalid, jnp.maximum(matched_counts, 1), 0)
+                    else:
+                        emit_counts = matched_counts
+                    total_l = jnp.sum(emit_counts)
+                    n_l = lkr.shape[0]
+                    li = jnp.repeat(jnp.arange(n_l, dtype=jnp.int32),
+                                    emit_counts, total_repeat_length=k_out)
+                    starts_ = jnp.cumsum(emit_counts) - emit_counts
+                    base = jnp.repeat(starts_.astype(jnp.int32), emit_counts,
+                                      total_repeat_length=k_out)
+                    within = jnp.arange(k_out, dtype=jnp.int32) - base
+                    is_match = within < jnp.take(matched_counts, li)
+                    ri = jnp.repeat(lo.astype(jnp.int32), emit_counts,
+                                    total_repeat_length=k_out) + \
+                        jnp.where(is_match, within, 0)
+                    ri = jnp.clip(ri, 0, max(rkr.shape[0] - 1, 0))
+
                     if preserve_right:
-                        base_v = vv if vv is not None else \
-                            jnp.ones(k_out, jnp.bool_)
-                        scat_v = (jnp.take(recv_r[f"v:{n}"], sort_r)
-                                  if f"v:{n}" in recv_r
-                                  else jnp.ones(rkr.shape[0], jnp.bool_))
-                        data = data.at[appendix_slot].set(
-                            col_sorted, mode="drop")
-                        vv = base_v.at[appendix_slot].set(
-                            scat_v, mode="drop")
-                    new_cols[n] = Column(dt, data, vv, dic)
-                if side.pack is None and not preserve_right:
-                    # Single-key, no appendix: the right key column is
-                    # rebuilt for free from the stream key (equal on
-                    # matches, null on left-outer padding) instead of
-                    # riding the exchange as duplicate payload.
-                    lname, rname = pairs[0]
-                    if rname in node.schema.names \
-                            and rname not in new_cols:
-                        lcm = side.stream_meta[lname]
-                        data = jnp.take(recv_l[f"d:{lname}"], li, axis=0)
-                        vv = (jnp.take(recv_l[f"v:{lname}"], li)
-                              if f"v:{lname}" in recv_l else None)
-                        if preserve_left:
-                            vv = is_match if vv is None else \
-                                (vv & is_match)
-                        new_cols[rname] = Column(lcm[0], data, vv, lcm[1])
-                table = Table(new_cols)
-                mask = out_mask
-                if jt == "inner":
-                    # Emitted match pairs across the mesh (inner: every
-                    # emit is a match; preserved-outer shapes are not
-                    # recorded, matching the executor's actuals policy).
-                    overflow_flags[f"jrows:{i}"] = jax.lax.psum(
+                        # Right rows whose key no received left row carries
+                        # emit once, appended after the matched block. The
+                        # left keys need their own sort for the probe.
+                        sort_l = kernels.lex_sort_indices(
+                            [(~lkvalid).astype(jnp.int32), lkr])
+                        lk_sorted = jnp.take(lkr, sort_l)
+                        lkv_sorted = jnp.take(lkvalid, sort_l)
+                        n_valid_l = jnp.sum(lkvalid.astype(jnp.int32))
+                        lk_probe = jnp.where(lkv_sorted, lk_sorted,
+                                             _max_sentinel(lk_sorted.dtype))
+                        lo_r = jnp.searchsorted(lk_probe, rk_sorted,
+                                                side="left")
+                        hi_r = jnp.minimum(
+                            jnp.searchsorted(lk_probe, rk_sorted,
+                                             side="right"),
+                            n_valid_l)
+                        r_unmatched = rvalid_sorted & \
+                            (~rkeyok_sorted | ((hi_r - lo_r) <= 0))
+                        appendix = jnp.sum(r_unmatched.astype(jnp.int32))
+                        appendix_pos = total_l + jnp.cumsum(
+                            r_unmatched.astype(jnp.int32)) - 1
+                        # mode="drop" discards slots at/above k_out.
+                        appendix_slot = jnp.where(r_unmatched, appendix_pos,
+                                                  k_out).astype(jnp.int32)
+                        total_eff = total_l + appendix
+                    else:
+                        appendix_slot = None
+                        total_eff = total_l
+                    overflow_flags[f"xof:{i}"] = jnp.maximum(
+                        overflow_flags[f"xof:{i}"],
+                        jax.lax.pmax((total_eff > k_out).astype(jnp.int32),
+                                     DATA_AXIS))
+                    # Exact per-device output need (counts are computed before
+                    # any slot clamping, so this is exact whenever the send
+                    # side fit — xneedc above cap marks the exception).
+                    overflow_flags[f"xneedo:{i}"] = jax.lax.pmax(
                         total_eff.astype(jnp.int32), DATA_AXIS)
+                    out_mask = jnp.arange(k_out, dtype=jnp.int32) < total_eff
+
+                    live = jnp.arange(k_out, dtype=jnp.int32) < total_l
+                    new_cols = {}
+                    for n in table.names:
+                        # Stream meta snapshot from prep time: projects below
+                        # this join may have created/redefined columns the
+                        # leaf col_meta doesn't describe.
+                        dt, dic, _ = side.stream_meta[n]
+                        data = jnp.take(recv_l[f"d:{n}"], li, axis=0)
+                        vv = (jnp.take(recv_l[f"v:{n}"], li)
+                              if f"v:{n}" in recv_l else None)
+                        if preserve_right:
+                            # Appendix rows have no left side: null-pad.
+                            vv = live if vv is None else (vv & live)
+                        new_cols[n] = Column(dt, data, vv, dic)
+                    for n, (dt, dic, nul) in side.table_meta.items():
+                        col_sorted = jnp.take(recv_r[f"d:{n}"], sort_r, axis=0)
+                        data = jnp.take(col_sorted, ri, axis=0)
+                        vv = (jnp.take(jnp.take(recv_r[f"v:{n}"], sort_r), ri)
+                              if f"v:{n}" in recv_r else None)
+                        if preserve_left:
+                            # Unmatched stream rows: right side is null.
+                            vv = is_match if vv is None else (vv & is_match)
+                        if preserve_right:
+                            base_v = vv if vv is not None else \
+                                jnp.ones(k_out, jnp.bool_)
+                            scat_v = (jnp.take(recv_r[f"v:{n}"], sort_r)
+                                      if f"v:{n}" in recv_r
+                                      else jnp.ones(rkr.shape[0], jnp.bool_))
+                            data = data.at[appendix_slot].set(
+                                col_sorted, mode="drop")
+                            vv = base_v.at[appendix_slot].set(
+                                scat_v, mode="drop")
+                        new_cols[n] = Column(dt, data, vv, dic)
+                    if side.pack is None and not preserve_right:
+                        # Single-key, no appendix: the right key column is
+                        # rebuilt for free from the stream key (equal on
+                        # matches, null on left-outer padding) instead of
+                        # riding the exchange as duplicate payload.
+                        lname, rname = pairs[0]
+                        if rname in node.schema.names \
+                                and rname not in new_cols:
+                            lcm = side.stream_meta[lname]
+                            data = jnp.take(recv_l[f"d:{lname}"], li, axis=0)
+                            vv = (jnp.take(recv_l[f"v:{lname}"], li)
+                                  if f"v:{lname}" in recv_l else None)
+                            if preserve_left:
+                                vv = is_match if vv is None else \
+                                    (vv & is_match)
+                            new_cols[rname] = Column(lcm[0], data, vv, lcm[1])
+                    table = Table(new_cols)
+                    mask = out_mask
+                    if jt == "inner":
+                        # Emitted match pairs across the mesh (inner: every
+                        # emit is a match; preserved-outer shapes are not
+                        # recorded, matching the executor's actuals policy).
+                        overflow_flags[f"jrows:{i}"] = jax.lax.psum(
+                            total_eff.astype(jnp.int32), DATA_AXIS)
 
         if mode == "sort":
-            # Distributed ORDER BY: range-partitioned sample sort (the
-            # TPU-native analogue of Spark's range-partitioned global
-            # sort consumed via exchange planning). Each device samples
-            # its primary sort key, splitters come back over one
-            # all_gather, rows route with one all_to_all, and each
-            # device's local lex sort finishes the job — the host then
-            # concatenates ALREADY-SORTED device ranges in rank order.
-            k0, asc0 = descr.sort_orders[0]
-            c0 = table.column(k0)
-            view = kernels._sort_key_view(c0.data, asc0)
-            if c0.validity is not None:
-                # Null placement (nulls first when ascending, last when
-                # descending) holds in view space by routing nulls to the
-                # extreme sentinel; the local sort places them exactly.
-                sentinel = _min_sentinel(view.dtype) if asc0 \
-                    else _max_sentinel(view.dtype)
-                view = jnp.where(c0.validity, view, sentinel)
+            with jax.named_scope("sample_sort"):
+                # Distributed ORDER BY: range-partitioned sample sort (the
+                # TPU-native analogue of Spark's range-partitioned global
+                # sort consumed via exchange planning). Each device samples
+                # its primary sort key, splitters come back over one
+                # all_gather, rows route with one all_to_all, and each
+                # device's local lex sort finishes the job — the host then
+                # concatenates ALREADY-SORTED device ranges in rank order.
+                k0, asc0 = descr.sort_orders[0]
+                c0 = table.column(k0)
+                view = kernels._sort_key_view(c0.data, asc0)
+                if c0.validity is not None:
+                    # Null placement (nulls first when ascending, last when
+                    # descending) holds in view space by routing nulls to the
+                    # extreme sentinel; the local sort places them exactly.
+                    sentinel = _min_sentinel(view.dtype) if asc0 \
+                        else _max_sentinel(view.dtype)
+                    view = jnp.where(c0.validity, view, sentinel)
 
-            order0 = kernels.lex_sort_indices(
-                [(~mask).astype(jnp.int32), view])
-            sorted_view = jnp.take(view, order0)
-            v_count = jnp.sum(mask.astype(jnp.int32))
-            k = _SORT_SAMPLES
-            pos = jnp.minimum((jnp.arange(k, dtype=jnp.int32) * v_count)
-                              // k, jnp.maximum(v_count - 1, 0))
-            samples = jnp.where(
-                v_count > 0, jnp.take(sorted_view, pos),
-                jnp.full(k, _max_sentinel(view.dtype), view.dtype))
-            all_samples = jax.lax.all_gather(
-                samples, DATA_AXIS).reshape(-1)
-            all_sorted = jnp.sort(all_samples)
-            total = n_dev * k
-            spl_pos = (jnp.arange(1, n_dev, dtype=jnp.int32) * total) \
-                // n_dev
-            splitters = jnp.take(all_sorted, spl_pos)
-            dst = jnp.searchsorted(splitters, view,
-                                   side="right").astype(jnp.int32)
+                order0 = kernels.lex_sort_indices(
+                    [(~mask).astype(jnp.int32), view])
+                sorted_view = jnp.take(view, order0)
+                v_count = jnp.sum(mask.astype(jnp.int32))
+                k = _SORT_SAMPLES
+                pos = jnp.minimum((jnp.arange(k, dtype=jnp.int32) * v_count)
+                                  // k, jnp.maximum(v_count - 1, 0))
+                samples = jnp.where(
+                    v_count > 0, jnp.take(sorted_view, pos),
+                    jnp.full(k, _max_sentinel(view.dtype), view.dtype))
+                all_samples = jax.lax.all_gather(
+                    samples, DATA_AXIS).reshape(-1)
+                all_sorted = jnp.sort(all_samples)
+                total = n_dev * k
+                spl_pos = (jnp.arange(1, n_dev, dtype=jnp.int32) * total) \
+                    // n_dev
+                splitters = jnp.take(all_sorted, spl_pos)
+                dst = jnp.searchsorted(splitters, view,
+                                       side="right").astype(jnp.int32)
 
-            arrays = {}
-            for n, nul in group_cols:
-                c = table.column(n)
-                arrays[f"d:{n}"] = c.data
-                if nul:
-                    arrays[f"v:{n}"] = c.validity \
-                        if c.validity is not None \
-                        else jnp.ones(c.data.shape[0], jnp.bool_)
-            cap = descr.caps[-1][0]
-            recv, rvalid, of, need = _a2a_exchange(
-                arrays, mask, dst, n_dev, cap)
-            out = dict(overflow_flags)
-            out["xof:-1"] = of
-            out["xneedc:-1"] = need
-            out["xneedo:-1"] = need
+                arrays = {}
+                for n, nul in group_cols:
+                    c = table.column(n)
+                    arrays[f"d:{n}"] = c.data
+                    if nul:
+                        arrays[f"v:{n}"] = c.validity \
+                            if c.validity is not None \
+                            else jnp.ones(c.data.shape[0], jnp.bool_)
+                cap = descr.caps[-1][0]
+                recv, rvalid, of, need = _a2a_exchange(
+                    arrays, mask, dst, n_dev, cap)
+                out = dict(overflow_flags)
+                out["xof:-1"] = of
+                out["xneedc:-1"] = need
+                out["xneedo:-1"] = need
 
-            keys = [(~rvalid).astype(jnp.int32)]
-            ascs = [True]
-            for n, asc in descr.sort_orders:
-                vkey = f"v:{n}"
-                data = recv[f"d:{n}"]
-                if vkey in recv:
-                    keys.append(recv[vkey].astype(jnp.int32))
+                keys = [(~rvalid).astype(jnp.int32)]
+                ascs = [True]
+                for n, asc in descr.sort_orders:
+                    vkey = f"v:{n}"
+                    data = recv[f"d:{n}"]
+                    if vkey in recv:
+                        keys.append(recv[vkey].astype(jnp.int32))
+                        ascs.append(asc)
+                        data = jnp.where(recv[vkey], data,
+                                         jnp.zeros((), data.dtype))
+                    keys.append(data)
                     ascs.append(asc)
-                    data = jnp.where(recv[vkey], data,
-                                     jnp.zeros((), data.dtype))
-                keys.append(data)
-                ascs.append(asc)
-            final = kernels.lex_sort_indices(keys, ascs)
-            out["omask"] = jnp.take(rvalid, final)
-            for n, nul in group_cols:
-                out[f"o:{n}"] = jnp.take(recv[f"d:{n}"], final, axis=0)
-                if nul:
-                    out[f"ov:{n}"] = jnp.take(recv[f"v:{n}"], final)
-            return out
+                final = kernels.lex_sort_indices(keys, ascs)
+                out["omask"] = jnp.take(rvalid, final)
+                for n, nul in group_cols:
+                    out[f"o:{n}"] = jnp.take(recv[f"d:{n}"], final, axis=0)
+                    if nul:
+                        out[f"ov:{n}"] = jnp.take(recv[f"v:{n}"], final)
+                return out
 
         if mode == "stream":
             # group_cols doubles as ((name, nullable), ...) in stream mode.
@@ -1980,8 +1998,9 @@ def _spmd_program(sharded, valid, bcast, xch, *, mesh: Mesh,
                 data = c.data
             key_flags.append(flag)
             key_datas.append(data)
-        order, s_mask, s_flags, s_datas, gids, local_groups = \
-            _group_segments(mask, key_flags, key_datas, G)
+        with jax.named_scope("group_sort"):
+            order, s_mask, s_flags, s_datas, gids, local_groups = \
+                _group_segments(mask, key_flags, key_datas, G)
         n_rows = s_mask.shape[0]
         overflow = jax.lax.pmax((local_groups > G).astype(jnp.int32),
                                 DATA_AXIS)
@@ -1989,25 +2008,26 @@ def _spmd_program(sharded, valid, bcast, xch, *, mesh: Mesh,
         # with this (distinct groups ≤ rows, so the retry always fits).
         gneed = jax.lax.pmax(local_groups, DATA_AXIS)
 
-        s_table = table.take(order)
-        fold = {
-            "sum": lambda v: kernels.segment_sum(v, gids, G),
-            "min": lambda v: kernels.segment_min(v, gids, G),
-            "max": lambda v: kernels.segment_max(v, gids, G),
-        }
-        out = {"overflow": overflow, "gneed": gneed}
-        out.update(overflow_flags)
-        for spec in agg_specs:
-            for k, v in spec.partials(s_table, s_mask, fold).items():
-                out[f"{spec.name}:{k}"] = v
-        firsts = jnp.minimum(kernels.segment_first_index(gids, G),
-                             n_rows - 1)
-        for g, flag, data in zip(group_cols, s_flags, s_datas):
-            out[f"g:{g}"] = jnp.take(data, firsts)
-            out[f"gf:{g}"] = jnp.ones(G, jnp.int32) if flag is None \
-                else jnp.take(flag, firsts)
-        out["gvalid"] = (jnp.arange(G, dtype=jnp.int32)
-                         < jnp.minimum(local_groups, G))
+        with jax.named_scope("partials"):
+            s_table = table.take(order)
+            fold = {
+                "sum": lambda v: kernels.segment_sum(v, gids, G),
+                "min": lambda v: kernels.segment_min(v, gids, G),
+                "max": lambda v: kernels.segment_max(v, gids, G),
+            }
+            out = {"overflow": overflow, "gneed": gneed}
+            out.update(overflow_flags)
+            for spec in agg_specs:
+                for k, v in spec.partials(s_table, s_mask, fold).items():
+                    out[f"{spec.name}:{k}"] = v
+            firsts = jnp.minimum(kernels.segment_first_index(gids, G),
+                                 n_rows - 1)
+            for g, flag, data in zip(group_cols, s_flags, s_datas):
+                out[f"g:{g}"] = jnp.take(data, firsts)
+                out[f"gf:{g}"] = jnp.ones(G, jnp.int32) if flag is None \
+                    else jnp.take(flag, firsts)
+            out["gvalid"] = (jnp.arange(G, dtype=jnp.int32)
+                             < jnp.minimum(local_groups, G))
 
         # ---- distributed final merge (the "final shuffle" on device) ----
         # Each partial group is hash-routed to its owner device with one
@@ -2022,52 +2042,53 @@ def _spmd_program(sharded, valid, bcast, xch, *, mesh: Mesh,
         # to the host merge instead; on real multi-chip the collective
         # rides ICI and the host stops being the merge bottleneck.
         if n_dev > 1 and routed_merge:
-            send = {k: v for k, v in out.items()
-                    if k not in ("overflow", "gvalid", "gneed")
-                    and not k.startswith(("xof:", "xneedc:",
-                                          "xneedo:", "jrows:"))}
-            gv = out["gvalid"]
-            h = None
-            for g in group_cols:
-                dt = table.column(g).dtype
-                ch = kernels.hash32_values(
-                    out[f"g:{g}"], INT32 if dt == STRING else dt)
-                ch = kernels.hash_combine(
-                    ch, out[f"gf:{g}"].astype(jnp.uint32))
-                h = ch if h is None else kernels.hash_combine(h, ch)
-            dst = (h % np.uint32(n_dev)).astype(jnp.int32)
-            recv, rvalid, _, _ = _a2a_exchange(send, gv, dst, n_dev, cap=G)
-            order2, m2, sflags2, sdatas2, gids2, owned = _group_segments(
-                rvalid, [None if f is None else recv[f"gf:{g}"]
-                         for g, f in zip(group_cols, key_flags)],
-                [recv[f"g:{g}"] for g in group_cols], G2)
-            nr = m2.shape[0]
-            out["gmof"] = jax.lax.pmax((owned > G2).astype(jnp.int32),
-                                       DATA_AXIS)
-            # Exact capacity an owner needs — _run retries ONCE with this
-            # (rounded up) instead of stepping blindly.
-            out["gmneed"] = jax.lax.pmax(owned, DATA_AXIS)
-            for spec in agg_specs:
-                for k in spec.partial_keys():
-                    v = jnp.take(recv[f"{spec.name}:{k}"], order2, axis=0)
-                    if k == "min":
-                        v = jnp.where(m2, v, _max_sentinel(v.dtype))
-                        merged = kernels.segment_min(v, gids2, G2)
-                    elif k == "max":
-                        v = jnp.where(m2, v, _min_sentinel(v.dtype))
-                        merged = kernels.segment_max(v, gids2, G2)
-                    else:  # sum / count merge by summation
-                        v = jnp.where(m2, v, jnp.zeros((), v.dtype))
-                        merged = kernels.segment_sum(v, gids2, G2)
-                    out[f"{spec.name}:{k}"] = merged
-            firsts2 = jnp.minimum(kernels.segment_first_index(gids2, G2),
-                                  nr - 1)
-            for g, f2, d2 in zip(group_cols, sflags2, sdatas2):
-                out[f"g:{g}"] = jnp.take(d2, firsts2)
-                out[f"gf:{g}"] = jnp.ones(G2, jnp.int32) if f2 is None \
-                    else jnp.take(f2, firsts2)
-            out["gvalid"] = (jnp.arange(G2, dtype=jnp.int32)
-                             < jnp.minimum(owned, G2))
+            with jax.named_scope("merge"):
+                send = {k: v for k, v in out.items()
+                        if k not in ("overflow", "gvalid", "gneed")
+                        and not k.startswith(("xof:", "xneedc:",
+                                              "xneedo:", "jrows:"))}
+                gv = out["gvalid"]
+                h = None
+                for g in group_cols:
+                    dt = table.column(g).dtype
+                    ch = kernels.hash32_values(
+                        out[f"g:{g}"], INT32 if dt == STRING else dt)
+                    ch = kernels.hash_combine(
+                        ch, out[f"gf:{g}"].astype(jnp.uint32))
+                    h = ch if h is None else kernels.hash_combine(h, ch)
+                dst = (h % np.uint32(n_dev)).astype(jnp.int32)
+                recv, rvalid, _, _ = _a2a_exchange(send, gv, dst, n_dev, cap=G)
+                order2, m2, sflags2, sdatas2, gids2, owned = _group_segments(
+                    rvalid, [None if f is None else recv[f"gf:{g}"]
+                             for g, f in zip(group_cols, key_flags)],
+                    [recv[f"g:{g}"] for g in group_cols], G2)
+                nr = m2.shape[0]
+                out["gmof"] = jax.lax.pmax((owned > G2).astype(jnp.int32),
+                                           DATA_AXIS)
+                # Exact capacity an owner needs — _run retries ONCE with this
+                # (rounded up) instead of stepping blindly.
+                out["gmneed"] = jax.lax.pmax(owned, DATA_AXIS)
+                for spec in agg_specs:
+                    for k in spec.partial_keys():
+                        v = jnp.take(recv[f"{spec.name}:{k}"], order2, axis=0)
+                        if k == "min":
+                            v = jnp.where(m2, v, _max_sentinel(v.dtype))
+                            merged = kernels.segment_min(v, gids2, G2)
+                        elif k == "max":
+                            v = jnp.where(m2, v, _min_sentinel(v.dtype))
+                            merged = kernels.segment_max(v, gids2, G2)
+                        else:  # sum / count merge by summation
+                            v = jnp.where(m2, v, jnp.zeros((), v.dtype))
+                            merged = kernels.segment_sum(v, gids2, G2)
+                        out[f"{spec.name}:{k}"] = merged
+                firsts2 = jnp.minimum(kernels.segment_first_index(gids2, G2),
+                                      nr - 1)
+                for g, f2, d2 in zip(group_cols, sflags2, sdatas2):
+                    out[f"g:{g}"] = jnp.take(d2, firsts2)
+                    out[f"gf:{g}"] = jnp.ones(G2, jnp.int32) if f2 is None \
+                        else jnp.take(f2, firsts2)
+                out["gvalid"] = (jnp.arange(G2, dtype=jnp.int32)
+                                 < jnp.minimum(owned, G2))
         else:
             out["gmof"] = jnp.zeros((), jnp.int32)
         return out
@@ -2103,11 +2124,19 @@ def _spmd_program(sharded, valid, bcast, xch, *, mesh: Mesh,
     for k in xof_keys:
         out_specs[k] = P()
 
+    # A stable program name (the HLO module ``jit_spmd_<mode>``) and
+    # named scopes for its phases, so a profile reads the same from
+    # compile to compile; XLA's instruction names do not.
+    program_name = f"spmd_{mode}"
+
     def global_view(sharded, valid, bcast, xch):
-        return device_view(
-            per_device, mesh,
-            in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(), P(DATA_AXIS)),
-            out_specs=out_specs)(sharded, valid, bcast, xch)
+        with jax.named_scope(program_name):
+            return device_view(
+                per_device, mesh,
+                in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(), P(DATA_AXIS)),
+                out_specs=out_specs)(sharded, valid, bcast, xch)
+
+    global_view.__name__ = global_view.__qualname__ = program_name
 
     # One bank entry per (stage fingerprint, mesh signature): the stage
     # fingerprint is the structural _StageDescr signature plus every
@@ -2129,6 +2158,13 @@ def _spmd_program(sharded, valid, bcast, xch, *, mesh: Mesh,
 # Host-side merges.
 # ---------------------------------------------------------------------------
 
+def _fetch(site: str, x) -> np.ndarray:
+    """One blocking device->host copy: a ``device.sync`` span at ``site``
+    when the query is traced."""
+    with _trace.span(SN.DEVICE_SYNC, node=site):
+        return np.asarray(jax.device_get(x))
+
+
 def _nullable_inputs(spec: _AggSpec, col_meta) -> bool:
     if spec.child is None:
         return False
@@ -2139,9 +2175,9 @@ def _nullable_inputs(spec: _AggSpec, col_meta) -> bool:
 def _merge_global(out, agg_specs, final_meta) -> Table:
     cols = {}
     for spec in agg_specs:
-        merged = {k: np.atleast_1d(np.asarray(
-            jax.device_get(out[f"{spec.name}:{k}"])))
-            for k in spec.partial_keys()}
+        merged = {k: np.atleast_1d(_fetch("spmd.merge",
+                                          out[f"{spec.name}:{k}"]))
+                  for k in spec.partial_keys()}
         cols[spec.name] = spec.finalize(
             merged, nullable_inputs=_nullable_inputs(spec, final_meta))
     return Table(cols)
@@ -2158,20 +2194,18 @@ def _merge_grouped(out, agg_specs, group_cols: List[str], col_meta,
                  + [f"{s.name}:{k}" for s in agg_specs
                     for k in s.partial_keys()])
         cap = int(out["gvalid"].shape[0])
-        n = int(np.asarray(jax.device_get(out["gneed"])))
+        n = int(_fetch("spmd.merge", out["gneed"]))
         stop = min(max(padded_length(n), 1), cap)
         out = dict(out)
         out.update(zip(names, kernels.slice_arrays(
             [out[k] for k in names], 0, stop)))
-    gvalid = np.asarray(jax.device_get(out["gvalid"]))
+    gvalid = _fetch("spmd.merge", out["gvalid"])
     sel = np.nonzero(gvalid)[0]
-    keys = [np.asarray(jax.device_get(out[f"g:{g}"]))[sel]
-            for g in group_cols]
-    flags = [np.asarray(jax.device_get(out[f"gf:{g}"]))[sel]
-             for g in group_cols]
-    partials = {f"{s.name}:{k}": np.asarray(
-        jax.device_get(out[f"{s.name}:{k}"]))[sel]
-        for s in agg_specs for k in s.partial_keys()}
+    keys = [_fetch("spmd.merge", out[f"g:{g}"])[sel] for g in group_cols]
+    flags = [_fetch("spmd.merge", out[f"gf:{g}"])[sel] for g in group_cols]
+    partials = {f"{s.name}:{k}": _fetch("spmd.merge",
+                                        out[f"{s.name}:{k}"])[sel]
+                for s in agg_specs for k in s.partial_keys()}
 
     # Merge-sort all per-device partial groups by (null-first, value) —
     # the same order the per-device sort used, and the output row order

@@ -21,12 +21,15 @@ from typing import List
 from ..index.constants import States
 from ..index.log_entry import IndexLogEntry
 from ..plan.nodes import IndexScan, LogicalPlan
+from ..telemetry import span_names as SN
+from ..telemetry import trace as _trace
 from .index_filters import CandidateIndexCollector, ReasonCollector
 
 
 def active_indexes(session) -> List[IndexLogEntry]:
     """ACTIVE indexes from the session's shared caching index manager."""
-    return session.index_collection_manager.get_indexes([States.ACTIVE])
+    with _trace.span(SN.INDEX_LOOKUP):
+        return session.index_collection_manager.get_indexes([States.ACTIVE])
 
 
 def _applied_index_names(plan: LogicalPlan) -> List[str]:
@@ -36,8 +39,6 @@ def _applied_index_names(plan: LogicalPlan) -> List[str]:
 
 def apply_hyperspace(session, plan: LogicalPlan,
                      ctx: ReasonCollector = None) -> LogicalPlan:
-    from ..telemetry import span_names as SN
-    from ..telemetry import trace as _trace
     with _trace.span(SN.INDEX_REWRITE) as sp:
         plan = _apply_hyperspace(session, plan, ctx)
         if sp is not None:

@@ -22,6 +22,8 @@ import pyarrow.parquet as pq
 from ..index.log_entry import IndexLogEntry, Sketch
 from ..plan import expr as E
 from ..plan.nodes import Filter, LogicalPlan, Scan
+from ..telemetry import span_names as SN
+from ..telemetry import trace as _trace
 from .rule_utils import _plan_signature, get_relation
 
 
@@ -61,7 +63,8 @@ class DataSkippingIndexRule:
         relation = get_relation(session, scan)
         if relation is None:
             return None
-        all_files = relation.all_files()
+        with _trace.span(SN.SOURCE_CHECK):
+            all_files = relation.all_files()
         keep = np.ones(len(all_files), dtype=bool)
         hit_names: List[str] = []
         for entry in candidates:
@@ -104,6 +107,18 @@ def evaluate_sketch_predicate(entry: IndexLogEntry, condition: E.Expr,
                               relation_schema) -> Optional[np.ndarray]:
     """Per-file keep mask from the entry's sketch table, or None when the
     predicate has no evaluable conjunct."""
+    with _trace.span(SN.SKIPPING_PROBE, files=len(all_files)) as sp:
+        keep = _evaluate_sketch_predicate(entry, condition, all_files,
+                                          relation_schema)
+        if sp is not None:
+            sp.attrs["kept"] = len(all_files) if keep is None \
+                else int(keep.sum())
+        return keep
+
+
+def _evaluate_sketch_predicate(entry: IndexLogEntry, condition: E.Expr,
+                               all_files: Sequence[str],
+                               relation_schema) -> Optional[np.ndarray]:
     table = _load_sketch_table(entry)
     by_file = {name: i for i, name in enumerate(table["_file"])}
     n_sketch = len(table["_file"])
@@ -259,20 +274,22 @@ def _eval_compare(column: str, op: str, value, table, sketch_by_col,
     return out
 
 
-# Tiny per-entry cache keyed on (index name, log id): sketch tables are small
-# and reread per query otherwise.
+# Tiny per-entry cache keyed on (sketch file path, log id): sketch tables
+# are small and reread per query otherwise. The path lies under the
+# index's own directory, so two lakes with same-named indexes never share
+# an entry.
 _SKETCH_CACHE: dict = {}
 
 
 def _load_sketch_table(entry: IndexLogEntry) -> dict:
     from ..actions.create_skipping import SKETCH_FILE_NAME
 
-    key = (entry.name, entry.id)
+    files = [f for f in entry.content.files
+             if os.path.basename(f) == SKETCH_FILE_NAME]
+    key = (files[0], entry.id)
     cached = _SKETCH_CACHE.get(key)
     if cached is not None:
         return cached
-    files = [f for f in entry.content.files
-             if os.path.basename(f) == SKETCH_FILE_NAME]
     from ..index import data_store
     _fs, _p0 = data_store.fs_and_path(files[0])
     t = pq.read_table(_p0, filesystem=_fs)

@@ -22,6 +22,8 @@ from ..index.signatures import LogicalPlanSignatureProvider
 from ..plan import expr as E
 from ..plan.nodes import Filter, IndexScan, LogicalPlan, Project, Scan
 from ..schema import Schema
+from ..telemetry import span_names as SN
+from ..telemetry import trace as _trace
 
 
 def log_index_usage(session, ctx, index_names: List[str], plan_string: str,
@@ -56,11 +58,14 @@ def get_relation(session, plan: LogicalPlan):
 
 
 def _plan_signature(entry: IndexLogEntry, scan: Scan) -> Optional[str]:
+    """The scan's signature under the index's recorded provider: for
+    file-based providers, a listing and stat of every source file."""
     recorded = entry.signature.signatures
     if not recorded:
         return None
-    provider = LogicalPlanSignatureProvider.create(recorded[0].provider)
-    return provider.signature(scan)
+    with _trace.span(SN.SOURCE_CHECK):
+        provider = LogicalPlanSignatureProvider.create(recorded[0].provider)
+        return provider.signature(scan)
 
 
 def _current_file_infos(relation) -> List[FileInfo]:

@@ -101,6 +101,10 @@ class QueryContext:
         # REAL outcome — counting both would show errors for queries
         # every client saw succeed).
         self.slo_suppress_error = False
+        # What Session.execute turns the answer into before the query
+        # ends (DataFrame.to_arrow: the host copy and the Arrow build),
+        # timed inside the query's trace; None returns the device Table.
+        self.finish = None
         # Per-query io counters; the lock is for cross-thread writers
         # (prefetch producers run in a copied context on another thread).
         self._io_lock = threading.Lock()

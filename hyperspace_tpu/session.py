@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Tuple, Union as TUnion
 
 from .config import Conf, HyperspaceConf
 from .exceptions import HyperspaceException
+from .execution.columnar import Table
 from .plan import expr as E
 from .plan.nodes import (Aggregate, Filter, Join, Limit, LogicalPlan, Project,
                          Scan, Sort, Union, Window)
@@ -294,48 +295,58 @@ class Session:
         # entirely (no contextvar write) while nothing is armed.
         with ctx.activate(), _faults.scope_for(self.hs_conf), \
                 _trace.maybe_profile(self), _trace.query_trace(self, ctx):
-            t0 = time.perf_counter()
-            error = False
-            suppress = False
-            try:
-                if not ctx.capture:
-                    return self._execute_uncaptured(plan, ctx)
-                # Advisor workload capture (advisor/workload.py): time
-                # whatever path actually runs and record the canonical
-                # plan + shapes + applied indexes. Resetting the reason
-                # collector first makes ``applied`` attributable to THIS
-                # execution (a result-cache hit runs no rewrite pass and
-                # records an empty applied set).
-                self._last_reason_collector = None
-                table = self._execute_uncaptured(plan, ctx)
-                from .advisor.workload import capture_execution
-                capture_execution(self, plan, time.perf_counter() - t0)
+            table = self._execute_observed(plan, ctx)
+            if ctx.finish is None:
                 return table
-            except BaseException as exc:
-                error = True
-                # A failed query is tail-keep-worthy by definition —
-                # and this is where worker-thread failures (whose emit
-                # sites never see the query's contextvars) surface on
-                # the query's own context.
-                _trace.keep_active("error")
-                # A sweep-member failure the frontend's ladder will
-                # rescue must not count as a completed failed query
-                # (the standalone rerun records the real outcome);
-                # deadline cancellations skip the rerun, so they stay.
-                from .exceptions import QueryDeadlineError
-                suppress = ctx.slo_suppress_error and \
-                    not isinstance(exc, QueryDeadlineError)
-                raise
-            finally:
-                # SLO sensor feed (telemetry/slo.py): every query's
-                # (latency, error, degraded) lands in the sliding window
-                # + the live query-latency histogram — inside the trace
-                # scope, so a breach event correlates with its query.
-                if not suppress:
-                    from .telemetry import slo as _slo
-                    _slo.observe_query(
-                        self, (time.perf_counter() - t0) * 1000.0,
-                        error=error, degraded=ctx.degraded)
+            # The answer's fetch to the host (DataFrame.to_arrow and
+            # friends) runs inside the query's trace: ``query`` ends
+            # after it.
+            with _trace.span(SN.RESULT_FETCH):
+                return ctx.finish(table)
+
+    def _execute_observed(self, plan: LogicalPlan, ctx):
+        t0 = time.perf_counter()
+        error = False
+        suppress = False
+        try:
+            if not ctx.capture:
+                return self._execute_uncaptured(plan, ctx)
+            # Advisor workload capture (advisor/workload.py): time
+            # whatever path actually runs and record the canonical
+            # plan + shapes + applied indexes. Resetting the reason
+            # collector first makes ``applied`` attributable to THIS
+            # execution (a result-cache hit runs no rewrite pass and
+            # records an empty applied set).
+            self._last_reason_collector = None
+            table = self._execute_uncaptured(plan, ctx)
+            from .advisor.workload import capture_execution
+            capture_execution(self, plan, time.perf_counter() - t0)
+            return table
+        except BaseException as exc:
+            error = True
+            # A failed query is tail-keep-worthy by definition — and
+            # this is where worker-thread failures (whose emit sites
+            # never see the query's contextvars) surface on the query's
+            # own context.
+            _trace.keep_active("error")
+            # A sweep-member failure the frontend's ladder will rescue
+            # must not count as a completed failed query (the standalone
+            # rerun records the real outcome); deadline cancellations
+            # skip the rerun, so they stay.
+            from .exceptions import QueryDeadlineError
+            suppress = ctx.slo_suppress_error and \
+                not isinstance(exc, QueryDeadlineError)
+            raise
+        finally:
+            # SLO sensor feed (telemetry/slo.py): every query's (latency,
+            # error, degraded) lands in the sliding window + the live
+            # query-latency histogram — inside the trace scope, so a
+            # breach event correlates with its query.
+            if not suppress:
+                from .telemetry import slo as _slo
+                _slo.observe_query(
+                    self, (time.perf_counter() - t0) * 1000.0,
+                    error=error, degraded=ctx.degraded)
 
     def _execute_uncaptured(self, plan: LogicalPlan, ctx=None):
         if not self.hs_conf.adaptive_replan_enabled():
@@ -579,11 +590,19 @@ class DataFrame:
     def optimized_plan(self) -> LogicalPlan:
         return self.session.optimize(self.plan)
 
+    def _fetched(self, finish):
+        """Run the plan and turn its device Table into ``finish(table)``
+        inside the query's trace (the ``result.fetch`` span)."""
+        from .serving.context import QueryContext
+        ctx = QueryContext.for_session(self.session)
+        ctx.finish = finish
+        return self.session.execute(self.plan, ctx)
+
     def to_arrow(self):
-        return self.execute().to_arrow()
+        return self._fetched(Table.to_arrow)
 
     def to_pandas(self):
-        return self.execute().to_pandas()
+        return self._fetched(Table.to_pandas)
 
     def collect(self) -> List[tuple]:
         table = self.to_arrow()

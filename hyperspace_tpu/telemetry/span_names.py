@@ -90,6 +90,38 @@ CLUSTER_FORWARD = "cluster.forward"
 CLUSTER_BROADCAST = "cluster.broadcast"
 CLUSTER_GATHER = "cluster.gather"
 
+# Inside the index rewrite (rules/): INDEX_LOOKUP is the index
+# manager's read of the ACTIVE entries (op-log reads included);
+# SOURCE_CHECK lists the relation's files, stats them and computes the
+# signature compared with the index's recorded one; SKIPPING_PROBE loads
+# (or hits) a data-skipping index's sketch table and evaluates the
+# filter over it (attrs carry files and kept).
+INDEX_LOOKUP = "rewrite.index_lookup"
+SOURCE_CHECK = "rewrite.source_check"
+SKIPPING_PROBE = "skipping.probe"
+
+# The SPMD tier's host work (execution/spmd.py): SPMD_GATE is the
+# per-query decision to go SPMD (parquet footer reads included); under
+# SPMD_DISPATCH, SPMD_PREPARE materializes and shards the leaf,
+# SPMD_LAUNCH looks up and enqueues the program, SPMD_MERGE builds the
+# answer from its outputs.
+SPMD_GATE = "spmd.gate"
+SPMD_PREPARE = "spmd.prepare"
+SPMD_LAUNCH = "spmd.launch"
+SPMD_MERGE = "spmd.merge"
+
+# One DEVICE_SYNC per blocking device->host round trip on the query
+# path (attr ``node`` names the site); RESULT_FETCH is the answer's copy
+# to the host and its Arrow build (DataFrame.to_arrow/collect/
+# to_pandas), inside the query's root span.
+DEVICE_SYNC = "device.sync"
+RESULT_FETCH = "result.fetch"
+
+# One XLA_COMPILE per backend compile or persistent-cache load inside a
+# traced query (execution/shapes.py, recorded after the fact; attrs carry
+# ``node`` = the jitted function's name, seconds, cached).
+XLA_COMPILE = "xla.compile"
+
 SPAN_NAMES = frozenset({
     QUERY, PLAN_NORMALIZE, JOIN_REORDER, INDEX_REWRITE, CACHE_LOOKUP,
     BANK_LOOKUP, BANK_COMPILE, EXEC_STAGE, EXEC_FUSED, IO_READ,
@@ -98,4 +130,7 @@ SPAN_NAMES = frozenset({
     INGEST_WAVE, INGEST_SOURCE,
     ARTIFACT_LOAD, ARTIFACT_EXPORT, ARTIFACT_WARMUP,
     CLUSTER_FORWARD, CLUSTER_BROADCAST, CLUSTER_GATHER,
+    INDEX_LOOKUP, SOURCE_CHECK, SKIPPING_PROBE,
+    SPMD_GATE, SPMD_PREPARE, SPMD_LAUNCH, SPMD_MERGE,
+    DEVICE_SYNC, RESULT_FETCH, XLA_COMPILE,
 })

@@ -31,6 +31,12 @@ live-latency threshold) can rescue exactly the unlucky queries — but
 the trace is DISCARDED at completion unless kept (:func:`finish_root`).
 Span NAMES come from the frozen registry in span_names.py — the
 scripts/lint.py span-discipline gate rejects free-form strings.
+
+While a ``jax.profiler`` capture records, every span opened by
+``span(...)`` (and the query root) is also a host ``TraceAnnotation``
+labelled like :func:`span_label`, so program spans sit on the
+profiler's clock beside the device ops in any xplane capture. Spans
+recorded after the fact (``add_span``) are not annotated.
 """
 
 from __future__ import annotations
@@ -205,15 +211,35 @@ class _NoopSpan:
 
 NOOP = _NoopSpan()
 
+# jax.profiler.TraceAnnotation (jaxlib's TraceMe), resolved on first use
+# so importing this module never imports jax.
+_TRACE_ME = None
+
+
+def _trace_me():
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME
+
+
+def span_label(name: str, attrs: dict) -> str:
+    """A span's name, with the ``node`` attribute where it has one
+    (``exec.stage:Filter``): the label of its profiler annotation."""
+    node = attrs.get("node")
+    return f"{name}:{node}" if node else name
+
 
 class _SpanScope:
-    __slots__ = ("_name", "_attrs", "_pair", "_token", "span")
+    __slots__ = ("_name", "_attrs", "_token", "_annotation", "span")
 
     def __init__(self, name: str, attrs: dict):
         self._name = name
         self._attrs = attrs
         self.span: Optional[Span] = None
         self._token = None
+        self._annotation = None
 
     def __enter__(self) -> Optional[Span]:
         pair = _ACTIVE.get()
@@ -227,10 +253,18 @@ class _SpanScope:
             return None
         self.span = span
         self._token = _ACTIVE.set((tr, span))
+        # While a profiler capture records, the span is also a host
+        # annotation, on the profiler's clock beside the device ops.
+        trace_me = _trace_me()
+        if trace_me.is_enabled():
+            self._annotation = trace_me(span_label(self._name, self._attrs))
+            self._annotation.__enter__()
         return span
 
     def __exit__(self, et, ev, tb):
         if self.span is not None:
+            if self._annotation is not None:
+                self._annotation.__exit__(et, ev, tb)
             if et is not None:
                 self.span.attrs["error"] = type(et).__name__
             self.span.finish()
@@ -434,9 +468,15 @@ def query_trace(session, ctx=None):
     if ctx is not None:
         ctx.trace = tr
     token = _ACTIVE.set((tr, root)) if root is not None else None
+    annotation = None
+    if root is not None and _trace_me().is_enabled():
+        annotation = _trace_me()(span_names.QUERY)
+        annotation.__enter__()
     try:
         yield root
     finally:
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         if root is not None:
             root.finish()
             _ACTIVE.reset(token)

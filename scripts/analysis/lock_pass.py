@@ -323,7 +323,7 @@ LOCK_GLOBALS = {
     ],
     "hyperspace_tpu/execution/shapes.py": [
         {"lock": "_counter_lock",
-         "names": {"_compile_total", "_compile_seconds", "_scope_counts",
+         "names": {"_compile_total", "_compile_seconds",
                    "_listener_installed"},
          "why": "the backend-compile counter fires from any thread "
                 "that triggers an XLA compile"},

@@ -130,6 +130,26 @@ class TestDataSkippingE2E:
         out = check_disable_and_compare(session, q)
         assert len(out) == 250
 
+    def test_same_index_name_in_two_lakes_prunes_each(self, tmp_path):
+        """Sketch tables are cached by the index's path: a second lake
+        whose skipping index has the same name (and log id) prunes by its
+        own sketches, not the first lake's."""
+        for lake, base in (("a", 0), ("b", 5000)):
+            df = pd.DataFrame({"k": np.arange(base, base + 800,
+                                              dtype=np.int64),
+                               "v": np.arange(800, dtype=np.int64)})
+            path = write_partitioned(tmp_path / lake, "data", df, "k", 8)
+            session = hst.Session(
+                system_path=str(tmp_path / lake / "indexes"))
+            src = session.read.parquet(path)
+            Hyperspace(session).create_index(src, DataSkippingIndexConfig(
+                "dsShared", [MinMaxSketch("k")]))
+            session.enable_hyperspace()
+            q = src.filter(col("k") < base + 100).select("k", "v")
+            assert len(scan_files(q.optimized_plan())) == 1
+            out = check_disable_and_compare(session, q)
+            assert len(out) == 100
+
     def test_bloom_prunes_equality(self, env):
         session, hs = env["session"], env["hs"]
         df = session.read.parquet(env["path"])

@@ -439,8 +439,8 @@ class TestResultCacheContracts:
 
     def test_cache_hit_on_fused_query_is_two_span_trace(self, mini):
         """Satellite regression: a result-cache HIT on a fused query must
-        still produce the exact {query, serving.cache_lookup} trace — no
-        exec.fused, no exec.stage, no reads."""
+        still produce the exact {query, serving.cache_lookup} trace, plus
+        the answer's fetch — no exec.fused, no exec.stage, no reads."""
         session, li_dir, od_dir = mini
         self._enable_cache(session)
         hs = Hyperspace(session)
@@ -452,7 +452,8 @@ class TestResultCacheContracts:
         hit = hs.last_trace()
         session.conf.set(TC.TRACE_ENABLED, "false")
         assert cold.find(sn.EXEC_FUSED) != []
-        assert {s.name for s in hit.spans} == {sn.QUERY, sn.CACHE_LOOKUP}
+        assert {s.name for s in hit.spans} - {sn.DEVICE_SYNC} == {
+            sn.QUERY, sn.CACHE_LOOKUP, sn.RESULT_FETCH}
         assert hit.find(sn.EXEC_FUSED) == []
         assert hit.find(sn.EXEC_STAGE) == []
 

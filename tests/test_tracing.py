@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 
 import numpy as np
 import pyarrow as pa
@@ -145,6 +146,18 @@ class TestTraceShape:
         fused = tr.find(sn.EXEC_FUSED)
         assert fused and max(s.attrs["fused_nodes"] for s in fused) >= 2
         assert any(s.parent_id in exec_ids for s in fused)
+        # Inside the rewrite: the index manager's lookup (no index, so
+        # no source check); the fused region's one scalar is a device
+        # sync under it; the answer's fetch hangs off the root.
+        by_id = {s.span_id: s for s in tr.spans}
+        rewrite = tr.find(sn.INDEX_REWRITE)[0]
+        assert any(s.parent_id == rewrite.span_id
+                   for s in tr.find(sn.INDEX_LOOKUP))
+        fused_ids = {s.span_id for s in fused}
+        assert any(s.parent_id in fused_ids and s.attrs["node"] ==
+                   "fused.groups" for s in tr.find(sn.DEVICE_SYNC))
+        (fetch,) = tr.find(sn.RESULT_FETCH)
+        assert by_id[fetch.parent_id].name == sn.QUERY
 
     def test_cold_vs_hit_traces_differ_at_cache_lookup(self, q3ish):
         session, li_dir, od_dir = q3ish
@@ -166,9 +179,11 @@ class TestTraceShape:
         assert cold_lookup[0].attrs["hit"] is False
         assert hit_lookup[0].attrs["hit"] is True
         assert hit_lookup[0].attrs["tier"] in ("device", "host")
-        # The hit trace is EXACTLY root + cache lookup: no optimize, no
-        # execution, no reads. The cold trace carries the rest.
-        assert {s.name for s in hit.spans} == {sn.QUERY, sn.CACHE_LOOKUP}
+        # The hit trace is EXACTLY root + cache lookup + the answer's
+        # fetch: no optimize, no execution, no reads. The cold trace
+        # carries the rest.
+        assert {s.name for s in hit.spans} - {sn.DEVICE_SYNC} == {
+            sn.QUERY, sn.CACHE_LOOKUP, sn.RESULT_FETCH}
         assert hit.find(sn.EXEC_STAGE) == []
         assert cold.find(sn.EXEC_STAGE) != []
 
@@ -332,7 +347,10 @@ class TestServingPropagation:
                 roots = [s for s in t.spans if s.parent_id is None]
                 assert [r.name for r in roots] == [sn.QUERY]
                 assert all(s.trace_id == t.trace_id for s in t.spans)
-                shapes.append(frozenset(s.name for s in t.spans))
+                # (Compiles land in whichever query happened to pay
+                # them, so they are no part of the structure.)
+                shapes.append(frozenset(s.name for s in t.spans
+                                        if s.name != sn.XLA_COMPILE))
             # Same query structure -> same span vocabulary, all 8 ways.
             assert len(set(shapes)) == 1
 
@@ -399,6 +417,9 @@ class TestSpanRegistry:
             "ingest.compact", "artifact.load", "artifact.export",
             "artifact.warmup", "cluster.forward", "cluster.broadcast",
             "cluster.gather", "ingest.source", "ingest.wave",
+            "rewrite.index_lookup", "rewrite.source_check",
+            "skipping.probe", "spmd.gate", "spmd.prepare", "spmd.launch",
+            "spmd.merge", "device.sync", "result.fetch", "xla.compile",
         })
 
     def test_join_reorder_span_appears_when_enabled(self, q3ish):
@@ -565,3 +586,274 @@ class TestSurfaces:
         for r, _d, files in os.walk(prof_dir):
             after.update(files)
         assert after == before
+
+
+# ---------------------------------------------------------------------------
+# The indexed-query host path: rewrite, skipping probe, SPMD gate and
+# dispatch, device syncs, result fetch, compiles; the profiler's clock.
+# ---------------------------------------------------------------------------
+
+Q6_FILES = 6
+Q6_ROWS_PER_FILE = 700
+
+
+@pytest.fixture()
+def q6ish(tmp_path):
+    """A miniature TPC-H Q6 lake: lineitem sorted by ship date over
+    several files, a MinMax data-skipping index on the date, and the
+    SPMD tier forced on over one device, as on one chip."""
+    rng = np.random.default_rng(6)
+    li_dir = tmp_path / "lineitem"
+    os.makedirs(li_dir)
+    for i in range(Q6_FILES):
+        n = Q6_ROWS_PER_FILE
+        pq.write_table(pa.table({
+            "l_shipdate": pa.array(np.sort(
+                rng.integers(i * 100, i * 100 + 100, n)).astype(np.int32)),
+            "l_discount": pa.array(rng.uniform(0, 0.1, n).round(2)),
+            "l_quantity": pa.array(rng.integers(1, 50, n).astype(np.float64)),
+            "l_extendedprice": pa.array(rng.uniform(1, 1000, n).round(2)),
+        }), os.path.join(li_dir, f"part{i}.parquet"))
+    session = hst.Session(system_path=str(tmp_path / "indexes"), conf={
+        IndexConstants.TPU_DISTRIBUTED_SINGLE_DEVICE: "on",
+        IndexConstants.TPU_DISTRIBUTED_MESH_MAX_DEVICES: "1",
+        IndexConstants.TPU_DISTRIBUTED_MIN_STREAM_ROWS: "0",
+        TC.TRACE_SAMPLE_RATE: "1.0"})
+    from hyperspace_tpu.api import DataSkippingIndexConfig, MinMaxSketch
+    Hyperspace(session).create_index(
+        session.read.parquet(str(li_dir)),
+        DataSkippingIndexConfig("li_ship_skip", [MinMaxSketch("l_shipdate")]))
+    session.enable_hyperspace()
+    return session, str(li_dir)
+
+
+def _build_q6(session, li_dir, year=2):
+    lo = year * 100
+    return (session.read.parquet(li_dir)
+            .filter((col("l_shipdate") >= lo) & (col("l_shipdate") < lo + 100)
+                    & (col("l_quantity") < 24))
+            .agg(sum_(col("l_extendedprice") * col("l_discount"))
+                 .alias("revenue")))
+
+
+def _children(tr, parent):
+    return [s for s in tr.spans if s.parent_id == parent.span_id]
+
+
+class TestHostPathSpans:
+    def test_q6_skipping_tree(self, q6ish):
+        session, li_dir = q6ish
+        hs = Hyperspace(session)
+        q = _build_q6(session, li_dir)
+        q.to_arrow()  # warm (compiles) untraced
+        _tracing(session, True)
+        q.to_arrow()
+        tr = hs.last_trace()
+        assert tr is not None and tr.dropped == 0
+        (root,) = [s for s in tr.spans if s.parent_id is None]
+        assert root.name == sn.QUERY
+        # query -> rewrite.index_rules -> {index lookup, source check,
+        # skipping probe}
+        (rewrite,) = tr.find(sn.INDEX_REWRITE)
+        assert rewrite.parent_id == root.span_id
+        under_rewrite = {s.name for s in _children(tr, rewrite)}
+        assert {sn.INDEX_LOOKUP, sn.SOURCE_CHECK,
+                sn.SKIPPING_PROBE} <= under_rewrite
+        (probe,) = tr.find(sn.SKIPPING_PROBE)
+        assert probe.attrs["files"] == Q6_FILES
+        assert probe.attrs["kept"] == 1
+        # The gate decides, then the dispatch prepares, launches, syncs
+        # and merges.
+        assert tr.find(sn.SPMD_GATE)
+        (dispatch,) = tr.find(sn.SPMD_DISPATCH)
+        under_dispatch = _children(tr, dispatch)
+        assert [s.name for s in under_dispatch] == [
+            sn.SPMD_PREPARE, sn.SPMD_LAUNCH, sn.SPMD_MERGE]
+        merge = under_dispatch[-1]
+        syncs = [s for s in tr.find(sn.DEVICE_SYNC)
+                 if s.parent_id == merge.span_id]
+        assert syncs and all(s.attrs["node"] == "spmd.merge" for s in syncs)
+        # Spans nest inside their parents.
+        by_id = {s.span_id: s for s in tr.spans}
+        for s in tr.spans:
+            if s.parent_id is not None:
+                p = by_id[s.parent_id]
+                assert p.start_perf <= s.start_perf
+                assert s.end_perf <= p.end_perf
+        # The answer's fetch is the root's last child: the query ends
+        # after it.
+        (fetch,) = tr.find(sn.RESULT_FETCH)
+        assert fetch.parent_id == root.span_id
+        assert _children(tr, root)[-1] is fetch
+        assert [s.attrs["node"] for s in _children(tr, fetch)] == ["result"]
+
+    def test_tracing_off_records_nothing_and_same_answer(self, q6ish):
+        session, li_dir = q6ish
+        hs = Hyperspace(session)
+        q = _build_q6(session, li_dir)
+        _tracing(session, False)
+        off = q.to_arrow()
+        assert hs.last_trace() is None
+        _tracing(session, True)
+        on = q.to_arrow()
+        assert hs.last_trace() is not None
+        _tracing(session, False)
+        off2 = q.to_arrow()
+        assert on.equals(off) and off2.equals(off)
+
+    def test_forced_compile_is_one_xla_compile_span(self, q6ish):
+        import jax
+        import jax.numpy as jnp
+        from hyperspace_tpu.serving.context import QueryContext
+        from hyperspace_tpu.telemetry import trace as trace_mod
+
+        session, _ = q6ish
+        _tracing(session, True)
+
+        def forced_compile_probe(x):
+            return jnp.cumsum(x) * 3
+
+        ctx = QueryContext.for_session(session)
+        with trace_mod.query_trace(session, ctx):
+            jax.jit(forced_compile_probe)(np.arange(11.0))
+        spans = [s for s in ctx.trace.find(sn.XLA_COMPILE)
+                 if "forced_compile_probe" in s.attrs["node"]]
+        assert len(spans) == 1
+        (s,) = spans
+        assert s.attrs["node"] == s.attrs["fun_name"]
+        assert s.attrs["seconds"] > 0 and s.attrs["cached"] is False
+        assert s.end_perf - s.start_perf == pytest.approx(
+            s.attrs["seconds"], abs=1e-6)
+
+    def test_compile_listener_marks_cache_loads(self, q6ish):
+        from hyperspace_tpu.execution import shapes
+        from hyperspace_tpu.serving.context import QueryContext
+        from hyperspace_tpu.telemetry import trace as trace_mod
+
+        session, _ = q6ish
+        _tracing(session, True)
+        ctx = QueryContext.for_session(session)
+        with trace_mod.query_trace(session, ctx):
+            shapes._on_compile_event(shapes._CACHE_HIT_EVENT, 0.001)
+            shapes._on_compile_event(shapes._COMPILE_EVENT, 0.002,
+                                     fun_name="jit(loaded)")
+            shapes._on_compile_event(shapes._COMPILE_EVENT, 0.003,
+                                     fun_name="jit(compiled)")
+        got = {s.attrs["node"]: s.attrs["cached"]
+               for s in ctx.trace.find(sn.XLA_COMPILE)}
+        assert got == {"jit(loaded)": True, "jit(compiled)": False}
+        # No trace active: the listener records nothing and leaves no
+        # mark for a later traced compile to inherit.
+        before = shapes.compile_count()
+        shapes._on_compile_event(shapes._CACHE_HIT_EVENT, 0.001)
+        shapes._on_compile_event(shapes._COMPILE_EVENT, 0.002,
+                                 fun_name="jit(untraced)")
+        assert shapes.compile_count() == before + 1
+        assert not getattr(shapes._cache_hit, "seen", False)
+
+
+def _host_events(xplane_path):
+    """(name, start_ns, end_ns, stats) of every host-plane event."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            dict(e.stats)))
+    return out
+
+
+def _capture(tmp_path, run):
+    """Profile ``run()`` under a ``bench.window`` annotation opened at a
+    known perf_counter instant, as the benchmark does; return the host
+    events and that instant."""
+    import glob
+    import time
+
+    import jax
+
+    out_dir = str(tmp_path / "xplane")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(out_dir, profiler_options=opts)
+    try:
+        win = jax.profiler.TraceAnnotation("bench.window")
+        perf0 = time.perf_counter()
+        win.__enter__()
+        run()
+        win.__exit__(None, None, None)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return _host_events(path), perf0
+
+
+class TestProfilerClock:
+    def test_program_spans_are_host_annotations_on_one_clock(
+            self, q6ish, tmp_path):
+        from hyperspace_tpu.telemetry.trace import span_label
+
+        session, li_dir = q6ish
+        hs = Hyperspace(session)
+        q = _build_q6(session, li_dir)
+        q.to_arrow()  # warm
+        _tracing(session, True)
+        events, perf0 = _capture(tmp_path, q.to_arrow)
+        tr = hs.last_trace()
+        (win,) = [e for e in events if e[0] == "bench.window"]
+        # The harness's one-anchor mapping of perf_counter onto the
+        # profiler's clock.
+        offset = win[1] - perf0 * 1e9
+        by_label = {}
+        for name, s, e, _ in events:
+            by_label.setdefault(name, []).append((s, e))
+        # Spans recorded after the fact (add_span) carry no annotation.
+        opened = [s for s in tr.spans if s.name != sn.XLA_COMPILE]
+        assert len(opened) >= 10
+        for sp in opened:
+            label = span_label(sp.name, sp.attrs)
+            assert label in by_label, label
+            mapped = sp.start_perf * 1e9 + offset
+            nearest = min(abs(mapped - s) for s, _ in by_label[label])
+            assert nearest < 0.5e6, (label, nearest)
+        # The SPMD program's device ops carry its stable module name.
+        modules = {st.get("hlo_module") for _, _, _, st in events}
+        assert "jit_spmd_agg" in modules
+
+    def test_no_annotation_without_a_trace(self, q6ish, tmp_path):
+        session, li_dir = q6ish
+        q = _build_q6(session, li_dir)
+        q.to_arrow()  # warm
+        _tracing(session, False)
+        events, _ = _capture(tmp_path, q.to_arrow)
+        names = {e[0] for e in events}
+        assert not names & (sn.SPAN_NAMES - {sn.QUERY})
+        assert sn.QUERY not in names
+
+
+class TestStableProgramNames:
+    def test_spmd_program_and_phase_scopes(self, q6ish):
+        from hyperspace_tpu.execution import spmd
+
+        session, li_dir = q6ish
+        _build_q6(session, li_dir, year=3).to_arrow()
+        prog, sig = spmd._LAST_PROGRAM
+        hlo = prog._compiled[sig][0].as_text()
+        assert "HloModule jit_spmd_agg" in hlo
+        # Ops carry the program's scope and their stage's (the per-device
+        # body runs under a vmap on one device).
+        op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+        assert any(n.startswith("jit(spmd_agg)/spmd_agg/")
+                   and "stage0_filter" in n for n in op_names)
+
+    def test_fused_region_name(self, q3ish, tmp_path):
+        session, li_dir, od_dir = q3ish
+        q = _build_q3(session, li_dir, od_dir)
+        q.to_arrow()  # warm: the region compiles here
+        events, _ = _capture(tmp_path, q.to_arrow)
+        modules = {st.get("hlo_module") for _, _, _, st in events}
+        assert "jit_fused_region" in modules
