@@ -32,12 +32,14 @@ keyed on (stage fingerprint, shape-class vector, mesh signature):
   (inner, left/right/full outer, semi, anti — Spark distributes all of
   them, so falling back would concede the reference's coverage):
   * broadcast (m:1): the non-stream side is materialized, required unique
-    on the key, key-sorted, replicated, and probed with a per-device
-    searchsorted. Multi-key joins probe a bit-packed composite built from
-    the broadcast side's per-column value ranges (out-of-range stream
-    values hit a sentinel that never matches). Left outer keeps unmatched
-    stream rows with the right columns invalid; semi/anti broadcast the
-    KEYS only (duplicates fine) and just mask the stream.
+    on the key, key-sorted, replicated, and probed per device: by one
+    gather from a direct-address position table when its integer keys
+    are dense, else by a searchsorted. Multi-key joins probe a bit-packed
+    composite built from the broadcast side's per-column value ranges
+    (out-of-range stream values hit a sentinel that never matches). Left
+    outer keeps unmatched stream rows with the right columns invalid;
+    semi/anti broadcast the KEYS only (duplicates fine) and just mask the
+    stream.
   * exchange (m:n): both sides are hash-routed over ICI with ONE
     lax.all_to_all each (value-stable key hash → owner device, the
     reference's shuffle join), then merge-joined locally into
@@ -268,8 +270,9 @@ def _needed_per_stage(needed: Set[str], stages):
 
 # ---------------------------------------------------------------------------
 # Join sides. Two strategies, chosen per join stage:
-#   broadcast — small m:1 side replicated to every device, probed with a
-#     searchsorted (the reference's broadcast join, primitive 4);
+#   broadcast — small m:1 side replicated to every device, probed through
+#     a position table or a searchsorted (the reference's broadcast join,
+#     primitive 4);
 #   exchange — both sides hash-routed over ICI with one all_to_all so equal
 #     keys meet on one device, then merge-joined locally (the reference's
 #     shuffle join, primitives 1+5). Handles m:n and big-big joins.
@@ -280,12 +283,21 @@ class _BroadcastSide:
     in the stream key's code space (null keys dropped — inner join),
     ``table`` row-aligned with ``keys``. ``pack`` is the multi-key
     composite spec: a tuple of (rmin, shift, sentinel) per key column —
-    None for single-key joins."""
+    None for single-key joins. ``slot``/``kmin`` is the direct-address
+    position table and its base key (kernels.position_table), or None
+    where the probe searches ``keys``."""
 
-    def __init__(self, keys: jax.Array, table: Table, pack=None):
+    def __init__(self, keys: jax.Array, table: Table, pack=None,
+                 slot=None, kmin=None):
         self.keys = keys
         self.table = table
         self.pack = pack
+        self.slot = slot
+        self.kmin = kmin
+
+    @property
+    def probe(self) -> str:
+        return "search" if self.slot is None else "direct"
 
 
 class _ExchangeSide:
@@ -368,23 +380,48 @@ def _pack_codes(codes):
 
 
 def _prepare_broadcast(right: Table, pairs, tiny: Dict[str, Column],
+                       stream_rows: int,
                        keys_only: bool = False) -> _BroadcastSide:
     """``keys_only`` (semi/anti probes) skips the m:1 uniqueness demand —
-    duplicate keys are harmless to an existence searchsorted — and ships
-    no data columns at all."""
+    duplicate keys are harmless to an existence probe — and ships no data
+    columns at all.
+
+    Integer keys (dates, string codes and packed composites included)
+    whose span is dense get a direct-address position table, so the probe
+    is one gather where a searchsorted takes log2(keys) dependent rounds.
+    The table is replicated like the side itself; it is built when its
+    int32 slots take no more bytes than the stream's probe-key column on
+    each device (``stream_rows`` per device at this stage). Its length
+    is the key span rounded up to a power of two: the span moves with
+    every change of the data, and an exact length would compile the
+    probing program anew each time."""
     right, _ = _drop_null_keys(right, [r for _, r in pairs])
     codes = [_right_key_codes(right, rname, tiny[lname])
              for lname, rname in pairs]
     keys, pack = _pack_codes(codes)
     order = kernels.lex_sort_indices([keys])
     keys = jnp.take(keys, order)
-    if keys_only:
-        return _BroadcastSide(keys, Table({}), pack)
-    right = right.take(order)
-    # m:1 requirement — broadcast side unique on the key (one host sync).
-    if keys.shape[0] > 1 and bool(jnp.any(keys[1:] == keys[:-1])):
+    table = Table({}) if keys_only else right.take(order)
+    n_r = int(keys.shape[0])
+    dense = n_r > 0 and jnp.issubdtype(keys.dtype, jnp.integer)
+    # One host sync for the m:1 requirement (the side unique on the key)
+    # and the key range.
+    ask = {}
+    if not keys_only and n_r > 1:
+        ask["dup"] = jnp.any(keys[1:] == keys[:-1])
+    if dense:
+        ask["lo"], ask["hi"] = keys[0], keys[-1]
+    got = jax.device_get(ask) if ask else {}
+    if got.get("dup"):
         raise _Unsupported("broadcast join side has duplicate keys")
-    return _BroadcastSide(keys, right, pack)
+    if dense:
+        length = _round_up_pow2(int(got["hi"]) - int(got["lo"]) + 1)
+        if 4 * length <= keys.dtype.itemsize * stream_rows:
+            with _trace.span(SN.JOIN_PROBE_TABLE, keys=n_r, slots=length):
+                slot, kmin = kernels.position_table(
+                    keys, length=length, unique=not keys_only)
+            return _BroadcastSide(keys, table, pack, slot, kmin)
+    return _BroadcastSide(keys, table, pack)
 
 
 def _prepare_exchange(right: Table, pairs, tiny: Dict[str, Column],
@@ -964,18 +1001,22 @@ def _prepare(root, executor, caps: Dict[int, Tuple[int, int]],
             # An _Unsupported here (e.g. composite bit overflow) falls
             # back to single-device — never to the exchange, which has
             # no existence-probe mode.
-            side = _prepare_broadcast(right_table, pairs, tiny,
+            side = _prepare_broadcast(right_table, pairs, tiny, out_rows,
                                       keys_only=True)
         elif jt in ("inner", "left"):
             # m:1 probe; left outer keeps unmatched stream rows with the
             # right columns invalid instead of masking them out.
             try:
-                side = _prepare_broadcast(right_table, pairs, tiny)
+                side = _prepare_broadcast(right_table, pairs, tiny, out_rows)
             except _Unsupported:
                 side = None
         if side is not None:
             joins[i] = ("b", pairs, side, jt)
-            bcast_arrays[f"k:{i}"] = side.keys
+            if side.slot is None:
+                bcast_arrays[f"k:{i}"] = side.keys
+            else:
+                bcast_arrays[f"s:{i}"] = side.slot
+                bcast_arrays[f"m:{i}"] = side.kmin
             for n in side.table.names:  # empty for keys_only sides
                 rc = side.table.column(n)
                 if n not in {r for _, r in pairs}:
@@ -1452,7 +1493,8 @@ class _StageDescr:
             else:
                 jkind, pairs, side, jt = joins[i]
                 parts.append(("J", jkind, jt, repr(node.condition),
-                              tuple(node.schema.names), side.pack))
+                              tuple(node.schema.names), side.pack,
+                              side.probe if jkind == "b" else None))
         for n, (dt, dic, nul) in sorted(col_meta.items()):
             parts.append((n, dt, _dict_fingerprint(dic), nul))
         for s in agg_specs:
@@ -1491,6 +1533,19 @@ def _stream_probe_key(table: Table, pairs, pack) -> Tuple[jax.Array, jax.Array]:
     if valid is None:
         valid = jnp.ones(comp.shape[0], jnp.bool_)
     return comp, valid
+
+
+def _direct_probe(lk, slot, kmin) -> Tuple[jax.Array, jax.Array]:
+    """(found, broadcast row) of each stream key through the side's
+    position table: one gather at ``lk - kmin``. A difference that wraps
+    in the key's width can land inside the table only at an offset whose
+    key would lie outside that width, so its slot holds -1."""
+    off = lk - kmin[0]
+    length = slot.shape[0]
+    in_range = (off >= 0) & (off < length)
+    pos = jnp.take(slot, jnp.clip(off, 0, length - 1).astype(jnp.int32),
+                   mode="clip")
+    return in_range & (pos >= 0), jnp.maximum(pos, 0)
 
 
 def _use_routed_merge(mesh: Mesh) -> bool:
@@ -1628,14 +1683,18 @@ def _spmd_program(sharded, valid, bcast, xch, *, mesh: Mesh,
                 elif joins[i][0] == "b":  # broadcast join probe
                     _, pairs, side, jt = joins[i]
                     lk, keys_valid = _stream_probe_key(table, pairs, side.pack)
-                    rkeys = bcast[f"k:{i}"]
-                    n_r = rkeys.shape[0]
-                    if n_r == 0:
+                    direct = side.probe == "direct"
+                    empty = not direct and bcast[f"k:{i}"].shape[0] == 0
+                    if direct:
+                        found, idx_c = _direct_probe(
+                            lk, bcast[f"s:{i}"], bcast[f"m:{i}"])
+                    elif empty:
                         found = jnp.zeros(lk.shape[0], jnp.bool_)
                         idx_c = jnp.zeros(lk.shape[0], jnp.int32)
                     else:
+                        rkeys = bcast[f"k:{i}"]
                         idx = jnp.searchsorted(rkeys, lk)
-                        idx_c = jnp.minimum(idx, n_r - 1)
+                        idx_c = jnp.minimum(idx, rkeys.shape[0] - 1)
                         found = jnp.take(rkeys, idx_c) == lk
                     found = found & keys_valid
                     if jt == "semi":
@@ -1662,7 +1721,7 @@ def _spmd_program(sharded, valid, bcast, xch, *, mesh: Mesh,
                         if n in rnames:
                             continue
                         rc = side.table.column(n)
-                        if n_r == 0:
+                        if empty:
                             data = jnp.zeros(lk.shape[0],
                                              _DEVICE_DTYPE[rc.dtype])
                             vv = None

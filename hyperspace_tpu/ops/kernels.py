@@ -392,6 +392,20 @@ def mask_count_nonzero(mask, valid_rows: Optional[int], padded: bool):
     return _nonzero_pad(mask, size=size), m
 
 
+@partial(jax.jit, static_argnames=("length", "unique"))
+def position_table(keys: jax.Array, length: int, unique: bool):
+    """Direct-address position table of ascending integer ``keys``:
+    ``slot[k - keys[0]]`` holds the row of key ``k`` (one of them where
+    keys repeat, ``unique`` False), -1 where no key falls. Returns
+    (slot, keys[:1]): the base key rides beside it as a 1-element array,
+    so the probing program takes it as data, not as a constant."""
+    kmin = keys[:1]
+    rows = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    slot = jnp.full(length, -1, jnp.int32).at[keys - kmin[0]].set(
+        rows, indices_are_sorted=True, unique_indices=unique)
+    return slot, kmin
+
+
 @partial(jax.jit, static_argnames=("dtype", "num_buckets", "check"))
 def _composite_bucket_key(keys: jax.Array, n, dtype: str,
                           num_buckets: int, check: bool):

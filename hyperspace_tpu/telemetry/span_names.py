@@ -110,6 +110,12 @@ SPMD_PREPARE = "spmd.prepare"
 SPMD_LAUNCH = "spmd.launch"
 SPMD_MERGE = "spmd.merge"
 
+# The build of a broadcast join's direct-address position table
+# (execution/spmd._prepare_broadcast), opened only when the side's keys
+# are dense enough for the probe to skip the binary search (attrs carry
+# keys and slots).
+JOIN_PROBE_TABLE = "join.probe_table"
+
 # One DEVICE_SYNC per blocking device->host round trip on the query
 # path (attr ``node`` names the site); RESULT_FETCH is the answer's copy
 # to the host and its Arrow build (DataFrame.to_arrow/collect/
@@ -131,6 +137,6 @@ SPAN_NAMES = frozenset({
     ARTIFACT_LOAD, ARTIFACT_EXPORT, ARTIFACT_WARMUP,
     CLUSTER_FORWARD, CLUSTER_BROADCAST, CLUSTER_GATHER,
     INDEX_LOOKUP, SOURCE_CHECK, SKIPPING_PROBE,
-    SPMD_GATE, SPMD_PREPARE, SPMD_LAUNCH, SPMD_MERGE,
+    SPMD_GATE, SPMD_PREPARE, SPMD_LAUNCH, SPMD_MERGE, JOIN_PROBE_TABLE,
     DEVICE_SYNC, RESULT_FETCH, XLA_COMPILE,
 })

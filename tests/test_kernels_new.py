@@ -191,7 +191,9 @@ class TestMultiKeyComposite:
         lb = rng.integers(-6, 6, 200).astype(np.int64)
         tiny = {"la": Column("int64", jnp.asarray(la)),
                 "lb": Column("int64", jnp.asarray(lb))}
-        side = _prepare_broadcast(right, [("la", "ra"), ("lb", "rb")], tiny)
+        # No stream rows to budget a position table: the side searches.
+        side = _prepare_broadcast(right, [("la", "ra"), ("lb", "rb")], tiny,
+                                  stream_rows=0)
         probe_table = Table({"la": tiny["la"], "lb": tiny["lb"]})
         lk, valid = _stream_probe_key(
             probe_table, [("la", "ra"), ("lb", "rb")], side.pack)

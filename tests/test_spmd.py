@@ -20,6 +20,8 @@ import hyperspace_tpu as hst
 from hyperspace_tpu.execution import spmd
 from hyperspace_tpu.index.constants import IndexConstants
 from hyperspace_tpu.plan.expr import avg, col, count, max_, min_, sum_
+from hyperspace_tpu.telemetry import span_names as sn
+from hyperspace_tpu.telemetry.constants import TelemetryConstants as TC
 
 
 @pytest.fixture()
@@ -256,6 +258,159 @@ class TestBroadcastJoin:
         t = pq.read_table(os.path.join(lineitem_dir, "part0.parquet"))
         counts = pd.Series(t.column("l_orderkey").to_numpy()).value_counts()
         assert out.to_pydict()["n"] == [int((counts ** 2).sum())]
+
+
+def _probe_tables(session, tmp_path, kind, how):
+    """Stream keys 0..119 against a broadcast side covering 10..89, so
+    stream keys fall below and above the side's range, in the key kind
+    ``kind``; semi/anti sides repeat every key. Returns (stream, side,
+    join condition, output columns)."""
+    rng = np.random.default_rng(61)
+    n = 6000  # 1,024 stream rows a device: room for the smallest table
+    k = rng.integers(0, 120, n)
+    dk = np.arange(10, 90)
+    if how in ("semi", "anti"):
+        dk = np.repeat(dk, 2)
+    dval = rng.integers(0, 9, len(dk)).astype(np.int64)
+    v = np.arange(n, dtype=np.int64)
+    on = col("k") == col("dk")
+    if kind == "packed":
+        fact = {"a": k % 10, "b": k // 10, "v": v}
+        dim = {"ra": dk % 10, "rb": dk // 10, "dval": dval}
+        on = (col("a") == col("ra")) & (col("b") == col("rb"))
+    else:
+        conv = {
+            "int64": lambda x: x.astype(np.int64),
+            "int32": lambda x: x.astype(np.int32),
+            "negative": lambda x: x.astype(np.int64) - 60,
+            "sparse": lambda x: x.astype(np.int64) * 1000,
+            "nulls": lambda x: x.astype(np.int64),
+            "empty": lambda x: x.astype(np.int64),
+            "date": lambda x: pa.array((x + 9000).astype("datetime64[D]")),
+            "string": lambda x: np.array([f"k{i:03d}" for i in x]),
+        }[kind]
+        fkey = conv(k)
+        if kind == "nulls":
+            fkey = pa.array(fkey, mask=rng.random(n) < 0.1)
+        fact = {"k": fkey, "v": v}
+        dim = {"dk": conv(dk), "dval": dval}
+    for name, t in (("fact", fact), ("dim", dim)):
+        d = tmp_path / name
+        d.mkdir()
+        pq.write_table(pa.table(t), str(d / "part0.parquet"))
+    lf = session.read.parquet(str(tmp_path / "fact"))
+    rf = session.read.parquet(str(tmp_path / "dim"))
+    if kind == "empty":
+        rf = rf.filter(col("dval") < 0)
+    cols = [c for c in fact] + ([] if how in ("semi", "anti") else ["dval"])
+    return lf, rf, on, cols
+
+
+def _probe_once(session, make_query):
+    """(rows sorted, probe mode) of one SPMD run; the mode is read from
+    the query's trace."""
+    before = spmd.DISPATCH_COUNT
+    out = make_query().to_pandas()
+    assert spmd.DISPATCH_COUNT > before, "SPMD path was not taken"
+    tr = hst.Hyperspace(session).last_trace()
+    mode = "direct" if tr.find(sn.JOIN_PROBE_TABLE) else "search"
+    return out.sort_values(list(out.columns)).reset_index(drop=True), mode
+
+
+def _search_only(monkeypatch):
+    """Give the broadcast side no stream rows to budget a table from, so
+    every probe searches."""
+    orig = spmd._prepare_broadcast
+    monkeypatch.setattr(
+        spmd, "_prepare_broadcast",
+        lambda right, pairs, tiny, stream_rows, keys_only=False:
+        orig(right, pairs, tiny, 0, keys_only))
+
+
+class TestDirectProbe:
+    """The broadcast probe through a position table gives the rows the
+    binary search gives; empty sides and sparse spans keep the search."""
+
+    @pytest.fixture(autouse=True)
+    def _traced(self, session):
+        session.conf.set(TC.TRACE_ENABLED, "true")
+
+    @pytest.mark.parametrize("kind,how,mode", [
+        ("int64", "inner", "direct"), ("int32", "inner", "direct"),
+        ("date", "inner", "direct"), ("string", "inner", "direct"),
+        ("packed", "inner", "direct"), ("negative", "inner", "direct"),
+        ("nulls", "inner", "direct"), ("int64", "left", "direct"),
+        ("nulls", "left", "direct"), ("int64", "semi", "direct"),
+        ("packed", "semi", "direct"), ("int64", "anti", "direct"),
+        ("nulls", "anti", "direct"), ("empty", "inner", "search"),
+        ("empty", "left", "search"), ("sparse", "inner", "search"),
+        ("sparse", "left", "search"),
+    ])
+    def test_rows_equal_the_search(self, session, tmp_path, monkeypatch,
+                                   kind, how, mode):
+        lf, rf, on, cols = _probe_tables(session, tmp_path, kind, how)
+
+        def query():
+            return lf.join(rf, on=on, how=how).select(*cols)
+
+        got, got_mode = _probe_once(session, query)
+        assert got_mode == mode
+        _search_only(monkeypatch)
+        searched, searched_mode = _probe_once(session, query)
+        assert searched_mode == "search"
+        pd.testing.assert_frame_equal(got, searched)
+        session.conf.set(IndexConstants.TPU_DISTRIBUTED_ENABLED, "false")
+        single = query().to_pandas()
+        single = single.sort_values(list(single.columns)) \
+            .reset_index(drop=True)
+        pd.testing.assert_frame_equal(got, single, check_dtype=False)
+
+    def test_direct_and_search_programs_bank_apart(self, session, tmp_path,
+                                                   monkeypatch):
+        from hyperspace_tpu.serving.program_bank import get_bank
+
+        lf, rf, on, cols = _probe_tables(session, tmp_path, "int64", "inner")
+
+        def query():
+            return lf.join(rf, on=on).select(*cols)
+
+        def stages():
+            return get_bank().stats()["stages_by_kind"].get("spmd", 0)
+
+        get_bank().clear()  # the other cases banked this query's shape
+        _, mode = _probe_once(session, query)
+        assert mode == "direct"
+        n_direct = stages()
+        _probe_once(session, query)
+        assert stages() == n_direct  # a repeat reuses its entry
+        _search_only(monkeypatch)
+        _, mode = _probe_once(session, query)
+        assert mode == "search"
+        assert stages() == n_direct + 1
+
+    def test_a_new_key_span_reuses_the_program(self, session, tmp_path):
+        from hyperspace_tpu.serving.program_bank import get_bank
+
+        lf, rf, on, cols = _probe_tables(session, tmp_path, "int64", "inner")
+        _, mode = _probe_once(
+            session, lambda: lf.join(rf, on=on).select(*cols))
+        assert mode == "direct"
+        programs = get_bank().stats()["programs"]
+        # As many keys, spanning 91 in place of 80: the table keeps its
+        # length class, so the program and its shapes stay the same.
+        dk = np.arange(10, 90)
+        dk[-1] = 100
+        d = tmp_path / "dim2"
+        d.mkdir()
+        pq.write_table(pa.table({"dk": dk.astype(np.int64),
+                                 "dval": np.zeros(80, np.int64)}),
+                       str(d / "part0.parquet"))
+        rf2 = session.read.parquet(str(d))
+        got, mode = _probe_once(
+            session, lambda: lf.join(rf2, on=on).select(*cols))
+        assert mode == "direct"
+        assert get_bank().stats()["programs"] == programs
+        assert set(got["k"]) == set(range(10, 89)) | {100}
 
 
 class TestNullables:

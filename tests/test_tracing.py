@@ -420,6 +420,7 @@ class TestSpanRegistry:
             "rewrite.index_lookup", "rewrite.source_check",
             "skipping.probe", "spmd.gate", "spmd.prepare", "spmd.launch",
             "spmd.merge", "device.sync", "result.fetch", "xla.compile",
+            "join.probe_table",
         })
 
     def test_join_reorder_span_appears_when_enabled(self, q3ish):
