@@ -1,6 +1,9 @@
-"""Run one cell once with the control in the program's place: each answer
-is replaced by the plain reference computed one precision lower (float32
-for the configuration's float64). The check has to come out false; the
+"""Run one cell once with the control in the program's place. In a query
+cell each answer is replaced by the plain reference computed one
+precision lower (float32 for the configuration's float64). In an ops cell
+each op module's ``control()`` breaks one guarantee the configuration
+states (``ops/lifecycle.py``: buckets by the key modulo the bucket count,
+in place of the engine's hash). The check has to come out false; the
 numbers it prints are the control's readings, the upper ends the limits
 in ``traffic/*.json`` are set below.
 
@@ -13,6 +16,7 @@ the cell asks for.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import sys
 
 import numpy as np
@@ -32,10 +36,16 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     root = run.prepare()
     import harness
+    cell = harness.load_cell(root, args.workload)
     try:
-        result = harness.run_cell(root, args.workload, args.seed,
-                                  args.seconds, False,
-                                  fault=lower_precision)
+        with contextlib.ExitStack() as stack:
+            fault = lower_precision
+            if "ops" in cell.traffic:
+                fault = None
+                for _, mod in harness.plan_ops(cell):
+                    stack.enter_context(mod.control())
+            result = harness.run_cell(root, args.workload, args.seed,
+                                      args.seconds, False, fault=fault)
     except harness.NoAccelerator as e:
         print(f"no result: {e}", file=sys.stderr)
         return 3

@@ -1,21 +1,34 @@
 """Run one benchmark cell once: the lake from the seed, the cell's indexes,
-a warm-up of every query the window will send, the measured window, the
+a warm-up of everything the window will send, the measured window, the
 check against the plain reference, and the metrics.
 
-Everything that belongs to one configuration, traffic mix, query kind or
-metric is found by name under the benchmark's directory:
+Everything that belongs to one configuration, traffic mix, query or op
+kind, or metric is found by name under the benchmark's directory:
 
 - ``configs/<config>.json``: the deployment (lake layout, indexes, session
   conf); ``BENCHMARK.json`` names the file;
-- ``traffic/<traffic>.json``: the queries, their literal sets, the
-  indexes each must be rewritten onto, the limits of the check, and the
-  mix's ``source``. One client sends them in a closed loop, as TPC-H's
-  power test does; a traffic file with any other key is refused;
+- ``traffic/<traffic>.json``: the mix's ``source``, the limits of the
+  check, and either ``queries`` (each with its literal sets and the
+  indexes it must be rewritten onto) or ``ops`` (operations on the lake's
+  indexes, such as an index's create, delete and vacuum). One client sends
+  them in a closed loop, as TPC-H's power test does; a traffic file with
+  any other key, or with both, is refused;
 - ``queries/<kind>.py``: how to build a query of that kind through the
   DataFrame API, and its plain reference on the host;
+- ``ops/<kind>.py``: how to run one cycle of an op of that kind
+  (``run``), its steps timed by the harness and its own reading of the
+  result between them, and the check of the window's cycles against its
+  plain reference (``check``). ``ops/lifecycle.py`` creates, deletes and
+  vacuums a covering index, and holds every cycle's files to the bucket
+  layout ``bucket_reference.py`` computes from the lake;
 - ``metrics/<name>.py`` (or ``metrics/<name up to its first dot>.py``): a
   reader that takes one metric, end-to-end or per-layer, from the
   window's record.
+
+Set-up builds the configuration's indexes that no op names. A query
+cell's window ends at the first completion after ``--seconds``; an ops
+cell's at the first cycle that completes after ``--seconds`` of summed
+step time, or at the first cycle that fails.
 
 A new cell therefore needs new files and entries in ``BENCHMARK.json``,
 and no edit of a file that is already there.
@@ -40,7 +53,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_DIR = os.path.basename(HERE)
 
 WINDOW_ANNOTATION = "bench.window"
-TRAFFIC_KEYS = {"source", "queries", "limits"}
+TRAFFIC_KEYS = {"source", "queries", "ops", "limits"}
 
 
 class NoAccelerator(RuntimeError):
@@ -88,6 +101,23 @@ class Sent:
 
 
 @dataclass
+class Cycle:
+    """One cycle of an op in the window: its steps, each timed on the host
+    clock, the harness's own work between them, and what the op's module
+    keeps for its check."""
+    op: dict
+    module: object
+    steps: List[tuple] = field(default_factory=list)    # (step, t0, t1)
+    asides: List[tuple] = field(default_factory=list)   # (label, t0, t1)
+    record: dict = field(default_factory=dict)
+    error: Optional[str] = None
+
+    @property
+    def step_s(self) -> float:
+        return sum(b - a for _, a, b in self.steps)
+
+
+@dataclass
 class Window:
     """What the metric readers read."""
     lake: object
@@ -101,6 +131,7 @@ class Window:
     device_kind: str
     trace: Optional[dict] = None
     skipping_indexes: Dict[str, str] = field(default_factory=dict)
+    cycles: List[Cycle] = field(default_factory=list)
 
 
 def _load_json(path: str) -> dict:
@@ -123,6 +154,9 @@ def load_cell(root: str, name: str) -> Cell:
     if unknown:
         raise ValueError(f"traffic {work['traffic']!r} has keys the harness "
                          f"does not implement: {sorted(unknown)}")
+    if ("queries" in traffic) == ("ops" in traffic):
+        raise ValueError(f"traffic {work['traffic']!r} needs one of "
+                         f"'queries' and 'ops', not both or neither")
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     e2e_names = {m["name"] for m in e2e}
@@ -298,6 +332,87 @@ def run_window(queries, seed: int, seconds: float, hs, trace: bool,
             return sent, t0, te
 
 
+@dataclass
+class OpContext:
+    """What an op's module drives and reads: the program's session and
+    API, the lake, the configuration, and the harness's timer."""
+    session: object
+    hs: object
+    lake: object
+    config: dict
+    annotate: object = None   # jax.profiler.TraceAnnotation in a traced run
+    fault: object = None      # for the tests: fault(cycle, step, ctx)
+    state: dict = field(default_factory=dict)  # the modules', per window
+
+    def build(self, indexes: List[dict]) -> None:
+        build_indexes(self.session, self.lake, indexes)
+
+    def step(self, cycle: Cycle, name: str, fn) -> None:
+        """Run one step of ``cycle`` and time it."""
+        t0 = time.perf_counter()
+        if self.annotate is not None:
+            with self.annotate("bench.op." + name):
+                fn()
+        else:
+            fn()
+        cycle.steps.append((name, t0, time.perf_counter()))
+        if self.fault is not None:
+            self.fault(cycle, name, self)
+
+    def aside(self, cycle: Cycle, label: str, fn) -> None:
+        """Run the harness's own work between steps, outside their time;
+        the traced run's breakdown names its idle time ``label``."""
+        t0 = time.perf_counter()
+        fn()
+        cycle.asides.append((label, t0, time.perf_counter()))
+
+
+def plan_ops(cell: Cell) -> List[tuple]:
+    """(op, its module) for each op of the traffic."""
+    return [(op, _module(cell.root, "ops", op["kind"], "bench_op_"))
+            for op in cell.traffic["ops"]]
+
+
+def run_cycle(ctx: OpContext, op: dict, module) -> Cycle:
+    c = Cycle(op=op, module=module)
+    try:
+        module.run(ctx, c)
+    except Exception as e:  # a failed cycle counts
+        c.error = f"{type(e).__name__}: {e}"
+    return c
+
+
+def run_ops_window(ops: List[tuple], seed: int, seconds: float,
+                   ctx: OpContext) -> tuple:
+    """Run cycles of the ops in a closed loop, each round of them in an
+    order drawn from the seed, until ``seconds`` of step time have passed;
+    the window ends at the first cycle that completes after that, or at
+    the first that fails, which leaves the index's state unknown."""
+    rng = np.random.default_rng(seed)
+    cycles: List[Cycle] = []
+    busy = 0.0
+    t0 = time.perf_counter()
+    while True:
+        for i in rng.permutation(len(ops)):
+            c = run_cycle(ctx, *ops[i])
+            cycles.append(c)
+            busy += c.step_s
+            if c.error is not None or busy >= seconds:
+                return cycles, t0, time.perf_counter()
+
+
+def check_ops(cell: Cell, lake, cycles: List[Cycle]) -> dict:
+    """The numbers that decide ``correct`` in an ops cell, each with its
+    limit: every op module's counts over its cycles of the window."""
+    values: Dict[str, float] = {}
+    for mod in {id(c.module): c.module for c in cycles}.values():
+        mine = [c for c in cycles if c.module is mod]
+        for k, v in mod.check(lake, cell.config, mine).items():
+            values[k] = values.get(k, 0) + v
+    limits = cell.traffic["limits"]
+    return {k: {"value": values[k], "limit": limits[k]} for k in values}
+
+
 def check(cell: Cell, lake, sent: List[Sent]) -> dict:
     """The numbers that decide ``correct``, each with its limit: every
     answer of the window held to the plain reference of its query,
@@ -330,7 +445,7 @@ def check(cell: Cell, lake, sent: List[Sent]) -> dict:
 
 
 def _trace_reduction(trace_dir: str, window_perf0: float,
-                     sent: List[Sent]) -> Optional[dict]:
+                     host_spans: List[tuple]) -> Optional[dict]:
     import trace_reduce as TR
 
     paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -341,11 +456,11 @@ def _trace_reduction(trace_dir: str, window_perf0: float,
     win = TR.find(prof.host, WINDOW_ANNOTATION)
     if win is None:
         return None
-    # The program's spans are on the host's perf_counter clock; the
-    # window annotation was opened at window_perf0 on that clock.
+    # The spans are on the host's perf_counter clock; the window
+    # annotation was opened at window_perf0 on that clock.
     offset = win[1] - window_perf0 * 1e9
     spans = [(name, a * 1e9 + offset, b * 1e9 + offset)
-             for s in sent for (name, a, b) in s.spans]
+             for (name, a, b) in host_spans]
     return TR.reduce(prof, win[1], win[2], spans)
 
 
@@ -390,7 +505,9 @@ def _compile_count() -> int:
 def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
              allow_cpu: bool = False, fault=None) -> dict:
     """Run the cell once and return the result line's object.
-    ``allow_cpu`` and ``fault`` are for the tests and the control."""
+    ``allow_cpu`` and ``fault`` are for the tests and the control: in a
+    query cell ``fault`` replaces each answer (``run_window``), in an ops
+    cell it runs after each step of the window (``OpContext.step``)."""
     t_start = _process_start_wall()
     cell = load_cell(root, name)
     for p in (HERE, os.path.dirname(HERE)):
@@ -421,16 +538,31 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         phases.done("lake")
         session = _session(cell, os.path.join(work, "indexes"), trace)
         hs = Hyperspace(session)
-        build_indexes(session, lake, cell.config["indexes"])
+        ops = plan_ops(cell) if "ops" in cell.traffic else []
+        named = {op["index"] for op, _ in ops}
+        build_indexes(session, lake, [ix for ix in cell.config["indexes"]
+                                      if ix["name"] not in named])
         phases.done("indexes")
-        session.enable_hyperspace()
-        queries = plan_queries(cell, session, lake)
-        # Warm every program, twice over: the first run of a query reads
-        # the files and fills the buffer pool, the second takes the pool's
-        # path, which runs programs of its own.
-        for _ in range(2):
-            for q in queries:
-                q.df.to_arrow()
+        queries: List[Query] = []
+        if ops:
+            ctx = OpContext(session=session, hs=hs, lake=lake,
+                            config=cell.config)
+            # Two whole cycles of every op, as every query runs twice.
+            for _ in range(2):
+                for op, mod in ops:
+                    c = run_cycle(ctx, op, mod)
+                    if c.error is not None:
+                        raise RuntimeError(f"warm-up cycle: {c.error}")
+            ctx.state.clear()
+        else:
+            session.enable_hyperspace()
+            queries = plan_queries(cell, session, lake)
+            # Warm every program, twice over: the first run of a query
+            # reads the files and fills the buffer pool, the second takes
+            # the pool's path, which runs programs of its own.
+            for _ in range(2):
+                for q in queries:
+                    q.df.to_arrow()
         phases.done("warm-up")
 
         EventSink.events.clear()
@@ -445,8 +577,15 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         window_perf0 = time.perf_counter()
         if trace:
             win.__enter__()
-        sent, t0, t1 = run_window(queries, seed, seconds, hs, trace, lake,
-                                  fault)
+        sent: List[Sent] = []
+        cycles: List[Cycle] = []
+        if ops:
+            ctx.annotate = jax.profiler.TraceAnnotation if trace else None
+            ctx.fault = fault
+            cycles, t0, t1 = run_ops_window(ops, seed, seconds, ctx)
+        else:
+            sent, t0, t1 = run_window(queries, seed, seconds, hs, trace,
+                                      lake, fault)
         if trace:
             win.__exit__(None, None, None)
             jax.profiler.stop_trace()
@@ -455,6 +594,8 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in devices[:cell.chips])
         del session, hs
+        if ops:
+            ctx.session = ctx.hs = None
         for q in queries:
             q.df = None
 
@@ -464,27 +605,36 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
             device_kind=devices[0].device_kind,
             skipping_indexes={ix["name"]: ix["table"]
                               for ix in cell.config["indexes"]
-                              if ix["kind"] == "skipping"})
+                              if ix["kind"] == "skipping"},
+            cycles=cycles)
         if trace:
-            window.trace = _trace_reduction(trace_dir, window_perf0, sent)
+            window.trace = _trace_reduction(
+                trace_dir, window_perf0,
+                [sp for s in sent for sp in s.spans]
+                + [a for c in cycles for a in c.asides])
         phases.done("window")
-        lat = sorted(s.t1 - s.t0 for s in sent)
-        print(f"window {len(sent)} queries in {t1 - t0:.3f} s, latency "
-              f"min {lat[0]:.4f} median {lat[len(lat) // 2]:.4f} max "
-              f"{lat[-1]:.4f} s", file=sys.stderr)
+        if ops:
+            _print_cycles(cycles, t1 - t0)
+        else:
+            lat = sorted(s.t1 - s.t0 for s in sent)
+            print(f"window {len(sent)} queries in {t1 - t0:.3f} s, latency "
+                  f"min {lat[0]:.4f} median {lat[len(lat) // 2]:.4f} max "
+                  f"{lat[-1]:.4f} s", file=sys.stderr)
         print(f"disk bytes written {_du(work)}", file=sys.stderr)
-        checks = check(cell, lake, sent)
+        checks = check_ops(cell, lake, cycles) if ops else \
+            check(cell, lake, sent)
         phases.done("check")
     finally:
         shutil.rmtree(work, ignore_errors=True)
 
-    failed = sum(1 for s in sent if s.error is not None)
-    correct = failed == 0 and bool(sent) and all(
+    done = cycles or sent
+    failed = sum(1 for s in done if s.error is not None)
+    correct = failed == 0 and bool(done) and all(
         c["value"] <= c["limit"] for c in checks.values())
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": peak}
-    result = {"correct": correct, "attempted": len(sent), "failed": failed,
+    result = {"correct": correct, "attempted": len(done), "failed": failed,
               "metrics": read_metrics(
                   root, cell.per_layer if trace else cell.end_to_end,
                   window, on_tpu),
@@ -502,6 +652,22 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
             k: v["value"] for k, v in read_metrics(
                 root, cell.end_to_end, window, on_tpu).items()}
     if failed:
-        result["errors"] = sorted({s.error for s in sent if s.error})[:5]
+        result["errors"] = sorted({s.error for s in done if s.error})[:5]
     result["checks"] = checks
     return result
+
+
+def _print_cycles(cycles: List[Cycle], wall_s: float) -> None:
+    """An ops window's cycles on standard error, for the record."""
+    steps: Dict[str, List[float]] = {}
+    for c in cycles:
+        for name, a, b in c.steps:
+            steps.setdefault(name, []).append(b - a)
+    aside = sum(b - a for c in cycles for _, a, b in c.asides)
+    means = ", ".join(f"{n} {1000 * sum(v) / len(v):.1f}" for n, v in
+                      steps.items())
+    print(f"window {len(cycles)} cycles in {wall_s:.3f} s: steps "
+          f"{sum(c.step_s for c in cycles):.3f} s, between steps "
+          f"{aside:.3f} s; mean step ms: {means}; index bytes written "
+          f"{sum(c.record.get('bytes', 0) for c in cycles)}",
+          file=sys.stderr)

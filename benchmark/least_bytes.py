@@ -1,5 +1,5 @@
-"""The least bytes of device memory a query has to read, at the columns'
-logical widths, from the lake's row counts.
+"""The least bytes of device memory a query has to read, or an index build
+has to move, at the columns' logical widths, from the lake's row counts.
 
 These are lower bounds on traffic, not models of an implementation: any
 plan that answers the query reads each needed value at least once, so the
@@ -23,8 +23,19 @@ WIDTH = {"int64": 8, "float64": 8, "date32": 4, "int32": 4}
 Q3_LINEITEM = ("int64", "float64", "float64", "date32")
 Q3_ORDERS = ("int64", "date32", "int32")
 
+# The lineitem covering index the build cell creates: l_orderkey (int64)
+# indexed; l_extendedprice, l_discount (float64) and l_shipdate (date32)
+# included.
+BUILD_LINEITEM = ("int64", "float64", "float64", "date32")
+
 
 def q3(lineitem_rows: int, orders_rows: int) -> int:
     """Q3's least bytes read: every row of the columns above, once."""
     return (lineitem_rows * sum(WIDTH[t] for t in Q3_LINEITEM)
             + orders_rows * sum(WIDTH[t] for t in Q3_ORDERS))
+
+
+def build(rows: int) -> int:
+    """An index build's least bytes: every indexed and included value of
+    the source read once and written once, in whatever order."""
+    return 2 * rows * sum(WIDTH[t] for t in BUILD_LINEITEM)

@@ -16,15 +16,28 @@ sys.path[:0] = [BENCH, REPO]
 
 TINY_SCALE = 0.002  # 3,000 orders, about 12,000 lineitem rows
 
+# The entries the index-build cell sf1_build takes in BENCHMARK.json. The
+# cell is left out of it while the program alters float64 values on the
+# TPU (PERF.md, section 7); its files are in the benchmark, and on the CPU,
+# where the program keeps the values, the tests run it from these.
+BUILD_ENTRIES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "data", "sf1_build_entries.json")
+
 
 @pytest.fixture
 def tiny_root(tmp_path):
-    """A checkout-like root holding BENCHMARK.json and a copy of the
-    benchmark directory whose configurations run at TINY_SCALE."""
+    """A checkout-like root holding BENCHMARK.json, with the sf1_build
+    cell's entries where it lacks them, and a copy of the benchmark
+    directory whose configurations run at TINY_SCALE."""
     root = tmp_path / "root"
     shutil.copytree(BENCH, root / "benchmark",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+    bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    extra = json.load(open(BUILD_ENTRIES))
+    if not any(w["name"] == "sf1_build" for w in bench["workloads"]):
+        for key, entries in extra.items():
+            bench[key].extend(entries)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
     for path in (root / "benchmark" / "configs").glob("*.json"):
         config = json.loads(path.read_text())
         config["scale_factor"] = TINY_SCALE

@@ -1,9 +1,11 @@
 """The harness end to end on the CPU at a tiny scale: each cell is correct
-against its plain reference, the control (the reference computed one
-precision lower, put in the program's place) and each fault the cells can
-have make ``correct`` false, a cell made of new files runs, and the
-command refuses to run without a TPU."""
+against its plain reference, the control (for a query, the reference
+computed one precision lower, put in the program's place; for an index
+build, buckets by the key modulo the bucket count) and each fault the
+cells can have make ``correct`` false, a cell made of new files runs, and
+the command refuses to run without a TPU."""
 
+import contextlib
 import json
 import os
 import subprocess
@@ -11,6 +13,7 @@ import sys
 
 import numpy as np
 import pyarrow as pa
+import pyarrow.parquet as pq
 import pytest
 
 import harness
@@ -18,9 +21,13 @@ import harness
 SEED = 2**31 + 101
 SECONDS = 0.3
 CELLS = ["sf1_q3_indexed", "sf1_q6_skipping"]
-SUFFIX = {"sf1_q3_indexed": "q3", "sf1_q6_skipping": "q6"}
+BUILD = "sf1_build"
+SUFFIX = {"sf1_q3_indexed": "q3", "sf1_q6_skipping": "q6",
+          BUILD: "build"}
 DEVICE_METRICS = ("device_ms_per_query.q3", "device_ms_per_query.q6",
-                  "q3_roofline", "idle_share.q3", "idle_share.q6")
+                  "q3_roofline", "idle_share.q3", "idle_share.q6",
+                  "device_ms_per_build", "build_roofline",
+                  "idle_share.build")
 
 
 def run(root, cell, trace=False, fault=None):
@@ -28,7 +35,7 @@ def run(root, cell, trace=False, fault=None):
                             allow_cpu=True, fault=fault)
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS + [BUILD])
 def test_cell_is_correct_and_reports_its_metrics(tiny_root, cell):
     res = run(tiny_root, cell)
     assert res["correct"], res["checks"]
@@ -40,7 +47,7 @@ def test_cell_is_correct_and_reports_its_metrics(tiny_root, cell):
     assert list(res)[-1] == "checks"
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS + [BUILD])
 def test_traced_run_reports_no_device_metric_off_the_chip(tiny_root, cell):
     res = run(tiny_root, cell, trace=True)
     assert res["correct"], res["checks"]
@@ -48,15 +55,23 @@ def test_traced_run_reports_no_device_metric_off_the_chip(tiny_root, cell):
     assert names and not names & set(DEVICE_METRICS)
     assert "busy_s" not in res["device"] and "breakdown" not in res
     assert res["metrics"][f"window_compiles.{SUFFIX[cell]}"]["value"] == 0
-    assert set(res["traced_end_to_end"]) == {"qps", "setup_s"}
+    want = {m["name"] for m in harness.load_cell(tiny_root, cell).end_to_end}
+    assert set(res["traced_end_to_end"]) == want
 
 
 def control(q, answer, lake):
     return q.module.reference(lake, q.spec, q.lits, np.float32)
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS + [BUILD])
 def test_control_fails(tiny_root, cell):
+    if cell == BUILD:
+        (_, lifecycle), = harness.plan_ops(harness.load_cell(tiny_root, cell))
+        with lifecycle.control():
+            res = run(tiny_root, cell)
+        assert not res["correct"]
+        assert res["checks"]["rows_misplaced"]["value"] > 0
+        return
     res = run(tiny_root, cell, fault=control)
     assert not res["correct"]
     c = res["checks"]
@@ -116,6 +131,102 @@ def test_rewrite_left_out_fails(tiny_root, cell, monkeypatch):
     assert res["checks"]["index_misses"]["value"] == res["attempted"]
 
 
+def index_files(ctx):
+    return ctx.hs.index_manager.get_index("li_idx").content.files
+
+
+def rewrite(path, table):
+    pq.write_table(table, path)
+
+
+def row_dropped(cycle, step, ctx):
+    if step == "create":
+        path = index_files(ctx)[0]
+        t = pq.read_table(path)
+        rewrite(path, t.slice(1))
+
+
+def bucket_halves(cycle, step, ctx):
+    """Every bucket file keeps its first half of rows: half of the batch
+    left out."""
+    if step == "create":
+        for path in index_files(ctx):
+            t = pq.read_table(path)
+            rewrite(path, t.slice(0, t.num_rows // 2))
+
+
+def row_moved(cycle, step, ctx):
+    """The first row of one bucket's file moves into the next bucket's
+    file, where it keeps that file sorted."""
+    if step == "create":
+        src, dst = index_files(ctx)[:2]
+        a, b = pq.read_table(src), pq.read_table(dst)
+        rewrite(src, a.slice(1))
+        rewrite(dst, pa.concat_tables([b, a.slice(0, 1)]).sort_by(
+            "l_orderkey"))
+
+
+def price_off_by_an_ulp(cycle, step, ctx):
+    """One row's l_extendedprice a unit in the last place off, as a
+    float64 that is not moved bit for bit reads."""
+    if step == "create":
+        path = index_files(ctx)[0]
+        t = pq.read_table(path)
+        price = t.column("l_extendedprice").to_numpy().copy()
+        price[0] = np.nextafter(price[0], 0.0)
+        rewrite(path, t.set_column(t.schema.get_field_index(
+            "l_extendedprice"), "l_extendedprice", pa.array(price)))
+
+
+def rows_swapped(cycle, step, ctx):
+    """The first and last rows of one bucket's file change places."""
+    if step == "create":
+        path = index_files(ctx)[0]
+        t = pq.read_table(path)
+        n = t.num_rows
+        assert t.column("l_orderkey")[0] != t.column("l_orderkey")[n - 1]
+        rewrite(path, t.take([n - 1] + list(range(1, n - 1)) + [0]))
+
+
+def vacuum_leaves(keep):
+    """A vacuum that leaves ``keep`` files of each version directory (all
+    of them where None: the step leaves its state unchanged)."""
+    def op(self):
+        for v in self.data_manager.get_all_version_ids():
+            d = self.data_manager.get_path(v)
+            names = sorted(os.listdir(d))
+            for f in names[len(names) if keep is None else keep:]:
+                os.remove(os.path.join(d, f))
+    return op
+
+
+# Each fault, and the one check it has to make fail; the others read 0.
+@pytest.mark.parametrize("fault,check", [
+    ("row_dropped", "rows_wrong"),
+    ("bucket_halves", "rows_wrong"),
+    ("price_off_by_an_ulp", "rows_wrong"),
+    ("row_moved", "rows_misplaced"),
+    ("rows_swapped", "unsorted_buckets"),
+    ("file_left_by_vacuum", "leftover_files"),
+    ("vacuum_unchanged", "leftover_files"),
+])
+def test_fault_in_the_index_fails(tiny_root, fault, check, monkeypatch):
+    hook = None
+    if fault in ("file_left_by_vacuum", "vacuum_unchanged"):
+        from hyperspace_tpu.actions.lifecycle import VacuumAction
+        monkeypatch.setattr(VacuumAction, "op", vacuum_leaves(
+            1 if fault == "file_left_by_vacuum" else None))
+    else:
+        hook = {"row_dropped": row_dropped, "bucket_halves": bucket_halves,
+                "price_off_by_an_ulp": price_off_by_an_ulp,
+                "row_moved": row_moved, "rows_swapped": rows_swapped}[fault]
+    res = run(tiny_root, BUILD, fault=hook)
+    assert not res["correct"], res["checks"]
+    values = {k: c["value"] for k, c in res["checks"].items()}
+    assert values.pop(check) > 0, res["checks"]
+    assert not any(values.values()), res["checks"]
+
+
 def test_new_cell_from_new_files_only(tiny_root):
     """A configuration, a traffic mix, an end-to-end metric and a per-layer
     metric added as new files, plus entries in BENCHMARK.json; no existing
@@ -160,6 +271,8 @@ def test_new_cell_from_new_files_only(tiny_root):
     bench["workloads"].append({
         "name": "tiny_plain", "config": "tpch_tiny_plain",
         "traffic": "q6_unindexed", "chips": 1, "why": "test"})
+    next(m for m in bench["end_to_end"]
+         if m["name"] == "qps")["workloads"].append("tiny_plain")
     bench["end_to_end"].append({
         "name": "window_queries", "unit": "queries", "better": "higher",
         "bound": 0.1, "source": "host_clock", "workloads": ["tiny_plain"]})
@@ -180,16 +293,24 @@ def test_new_cell_from_new_files_only(tiny_root):
         assert open(p, "rb").read() == data, p
 
 
-def test_traffic_the_harness_does_not_implement_is_refused(tiny_root):
-    """An open-loop or many-client mix needs code the harness lacks: it is
-    refused, not run as one closed-loop client."""
-    path = os.path.join(tiny_root, "benchmark", "traffic", "q6_power.json")
-    traffic = json.load(open(path))
-    traffic["clients"] = 2
+@pytest.mark.parametrize("cell,traffic,key,match", [
+    ("sf1_q6_skipping", "q6_power", "clients", "clients"),
+    (BUILD, "li_idx_lifecycle", "queries", "not both"),
+])
+def test_traffic_the_harness_does_not_implement_is_refused(
+        tiny_root, cell, traffic, key, match):
+    """An open-loop or many-client mix, or one that mixes queries and ops,
+    needs code the harness lacks: it is refused, not run as one
+    closed-loop client."""
+    path = os.path.join(tiny_root, "benchmark", "traffic", traffic + ".json")
+    mix = json.load(open(path))
+    mix[key] = json.load(open(os.path.join(
+        tiny_root, "benchmark", "traffic", "q6_power.json")))["queries"] \
+        if key == "queries" else 2
     with open(path, "w") as fh:
-        json.dump(traffic, fh)
-    with pytest.raises(ValueError, match="clients"):
-        harness.load_cell(tiny_root, "sf1_q6_skipping")
+        json.dump(mix, fh)
+    with pytest.raises(ValueError, match=match):
+        harness.load_cell(tiny_root, cell)
 
 
 def test_command_refuses_without_a_tpu(tiny_root):
