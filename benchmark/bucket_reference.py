@@ -18,6 +18,7 @@ sorted by the indexed columns.
 from __future__ import annotations
 
 import re
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
@@ -25,6 +26,7 @@ import pyarrow as pa
 
 GOLDEN32 = np.uint32(0x9E3779B9)
 BUCKET_FILE = re.compile(r"part-(\d{5})")
+COMPARE_PARTS = 8
 
 # (rows, 64-bit sum of the row hashes, sorted by the indexed columns)
 Digest = Dict[int, Tuple[int, int, bool]]
@@ -139,31 +141,41 @@ def reference_digest(source: pa.Table, buckets: np.ndarray,
                      cols: Sequence[str]) -> Digest:
     """The digest of the index the source's rows make: each row in its
     bucket, every bucket sorted."""
-    order = np.argsort(buckets, kind="stable")
-    b = buckets[order]
-    h = row_hash(source, cols)[order]
-    heads = np.flatnonzero(np.r_[True, b[1:] != b[:-1]])
-    sums = np.add.reduceat(h, heads)
-    counts = np.diff(np.r_[heads, len(b)])
-    return {int(b[i]): (int(n), int(s), True)
-            for i, n, s in zip(heads, counts, sums)}
+    counts = np.bincount(buckets)
+    sums = np.zeros(len(counts), np.uint64)
+    np.add.at(sums, buckets, row_hash(source, cols))
+    return {int(b): (int(counts[b]), int(sums[b]), True)
+            for b in np.flatnonzero(counts)}
+
+
+def _unmatched(t: pa.Table) -> int:
+    keys = [c for c in t.column_names if c != "side"]
+    g = t.group_by(keys, use_threads=False).aggregate(
+        [("side", "sum"), ("side", "count")])
+    n_got = g.column("side_sum").to_numpy()
+    n_all = g.column("side_count").to_numpy()
+    return int(np.abs(n_all - 2 * n_got).sum())
 
 
 def rows_wrong(got: pa.Table, want: pa.Table, cols: Sequence[str]) -> int:
     """Rows missing from ``got`` or extra in it, against ``want``, as
-    multisets of the values in ``cols``."""
-    keys = [f"k{i}" for i in range(len(cols))]
-
-    def keyed(t: pa.Table, side: int) -> pa.Table:
-        d = {k: bits64(t.column(c)) for k, c in zip(keys, cols)}
-        d["side"] = np.full(t.num_rows, side, np.int64)
-        return pa.table(d)
-
-    g = pa.concat_tables([keyed(want, 0), keyed(got, 1)]).group_by(
-        keys).aggregate([("side", "sum"), ("side", "count")])
-    n_got = g.column("side_sum").to_numpy()
-    n_all = g.column("side_count").to_numpy()
-    return int(np.abs(n_all - 2 * n_got).sum())
+    multisets of the values in ``cols``. Equal rows have equal first
+    values, so the rows are split by a hash of their first value into
+    ``COMPARE_PARTS`` parts, compared in as many threads."""
+    sides = (want, got)
+    bits = {f"k{i}": np.concatenate([bits64(t.column(c)) for t in sides])
+            for i, c in enumerate(cols)}
+    bits["side"] = np.repeat(np.array([0, 1], np.int64),
+                             [t.num_rows for t in sides])
+    part = (_mix64(bits["k0"].copy()) % np.uint64(COMPARE_PARTS)).astype(
+        np.uint8)
+    order = np.argsort(part, kind="stable")
+    ends = np.cumsum(np.bincount(part, minlength=COMPARE_PARTS))
+    rows = {k: v[order] for k, v in bits.items()}
+    tables = [pa.table({k: v[a:b] for k, v in rows.items()})
+              for a, b in zip(np.r_[0, ends[:-1]], ends)]
+    with ThreadPoolExecutor(COMPARE_PARTS) as pool:
+        return sum(pool.map(_unmatched, tables))
 
 
 def compare(parts: List[Tuple[int, pa.Table]], source: pa.Table,

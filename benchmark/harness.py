@@ -25,10 +25,11 @@ kind, or metric is found by name under the benchmark's directory:
   reader that takes one metric, end-to-end or per-layer, from the
   window's record.
 
-Set-up builds the configuration's indexes that no op names. A query
-cell's window ends at the first completion after ``--seconds``; an ops
-cell's at the first cycle that completes after ``--seconds`` of summed
-step time, or at the first cycle that fails.
+Set-up builds the configuration's indexes that no op names. Both kinds
+of window run on the wall clock: a query cell's ends at the first
+completion after ``--seconds``; an ops cell's at the first cycle that
+completes after ``--seconds``, the harness's own reads between steps
+included, or at the first cycle that fails.
 
 A new cell therefore needs new files and entries in ``BENCHMARK.json``,
 and no edit of a file that is already there.
@@ -44,6 +45,7 @@ import shutil
 import sys
 import tempfile
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -385,20 +387,22 @@ def run_cycle(ctx: OpContext, op: dict, module) -> Cycle:
 def run_ops_window(ops: List[tuple], seed: int, seconds: float,
                    ctx: OpContext) -> tuple:
     """Run cycles of the ops in a closed loop, each round of them in an
-    order drawn from the seed, until ``seconds`` of step time have passed;
-    the window ends at the first cycle that completes after that, or at
-    the first that fails, which leaves the index's state unknown."""
+    order drawn from the seed, until ``seconds`` have passed on the wall
+    clock, the cycles' asides (the digests) included, as a query window's
+    time includes the harness's own work; the window ends at the first
+    cycle that completes after that, or at the first that fails, which
+    leaves the index's state unknown. A rate over the cycles divides by
+    their summed step time, which leaves the asides out."""
     rng = np.random.default_rng(seed)
     cycles: List[Cycle] = []
-    busy = 0.0
     t0 = time.perf_counter()
     while True:
         for i in rng.permutation(len(ops)):
             c = run_cycle(ctx, *ops[i])
             cycles.append(c)
-            busy += c.step_s
-            if c.error is not None or busy >= seconds:
-                return cycles, t0, time.perf_counter()
+            t1 = time.perf_counter()
+            if c.error is not None or t1 - t0 >= seconds:
+                return cycles, t0, t1
 
 
 def check_ops(cell: Cell, lake, cycles: List[Cycle]) -> dict:
@@ -515,26 +519,30 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
             sys.path.insert(0, p)
     import jax
 
-    devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-    if (not on_tpu and not allow_cpu) or len(devices) < cell.chips:
-        raise NoAccelerator(
-            f"cell {name} needs {cell.chips} TPU chip(s); JAX found "
-            f"{len(devices)} {devices[0].platform} device(s)")
-    if on_tpu:
-        import peaks
-        peaks.peak(devices[0].device_kind)  # an unknown chip is an error
-
-    from hyperspace_tpu.api import Hyperspace
-    from hyperspace_tpu.execution import shapes
-
     import lake as L
-    from event_sink import EventSink
 
     work = tempfile.mkdtemp(prefix="hsbench-")
     try:
         phases = _Phases(t_start)
-        lake = L.write_lake(os.path.join(work, "lake"), cell.config, seed)
+        # The runtime takes seconds to find a TPU; the lake is written
+        # meanwhile, in numpy and pyarrow, which release the GIL.
+        with ThreadPoolExecutor(1) as pool:
+            writing = pool.submit(L.write_lake, os.path.join(work, "lake"),
+                                  cell.config, seed)
+            devices = jax.devices()
+            on_tpu = devices[0].platform == "tpu"
+            if (not on_tpu and not allow_cpu) or len(devices) < cell.chips:
+                raise NoAccelerator(
+                    f"cell {name} needs {cell.chips} TPU chip(s); JAX "
+                    f"found {len(devices)} {devices[0].platform} device(s)")
+            if on_tpu:
+                import peaks
+                peaks.peak(devices[0].device_kind)  # an unknown chip fails
+            from hyperspace_tpu.api import Hyperspace
+            from hyperspace_tpu.execution import shapes
+
+            from event_sink import EventSink
+            lake = writing.result()
         phases.done("lake")
         session = _session(cell, os.path.join(work, "indexes"), trace)
         hs = Hyperspace(session)

@@ -80,3 +80,44 @@ def test_digest_and_compare_see_each_fault(table):
         got = R.compare(faulty, table, ["i64"], cols, 16)
         assert got.pop(check) == 1, check
         assert not any(got.values()), check
+
+
+def _faulty(table, fault):
+    """``table`` with one kind of fault, its rows shuffled."""
+    f64 = table.column("f64").to_numpy().copy()
+    if fault == "row_dropped":
+        table = table.slice(1)
+    elif fault == "rows_repeated":
+        table = pa.concat_tables([table, table.slice(0, 10)])
+    elif fault == "value_off_by_an_ulp":
+        f64[3] = np.nextafter(f64[3], np.inf)
+        table = table.set_column(1, "f64", pa.array(f64))
+    elif fault == "values_swapped_under_one_key":
+        i, j = np.flatnonzero(table.column("i64").to_numpy() ==
+                              table.column("i64")[0].as_py())[:2]
+        f64[[i, j]] = f64[[j, i]]
+        table = table.set_column(1, "f64", pa.array(f64))
+    return table.take(np.random.default_rng(3).permutation(table.num_rows))
+
+
+@pytest.mark.parametrize("fault", ["none", "row_dropped", "rows_repeated",
+                                   "value_off_by_an_ulp",
+                                   "values_swapped_under_one_key"])
+def test_rows_wrong_is_the_multiset_difference(table, fault):
+    """Rows that share their first value, the one the comparison splits
+    its work by, and repeated rows, counted as a plain multiset count
+    does."""
+    from collections import Counter
+
+    cols = table.column_names
+    keyed = table.set_column(0, "i64", pa.array(
+        table.column("i64").to_numpy() % 50))
+    got = _faulty(keyed, fault)
+
+    def rows(t):
+        return Counter(zip(*(R.bits64(t.column(c)).tolist() for c in cols)))
+
+    g, w = rows(got), rows(keyed)
+    want = sum(((g - w) + (w - g)).values())
+    assert R.rows_wrong(got, keyed, cols) == want
+    assert (want == 0) == (fault == "none")

@@ -10,6 +10,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pyarrow as pa
@@ -225,6 +226,41 @@ def test_fault_in_the_index_fails(tiny_root, fault, check, monkeypatch):
     values = {k: c["value"] for k, c in res["checks"].items()}
     assert values.pop(check) > 0, res["checks"]
     assert not any(values.values()), res["checks"]
+
+
+def test_ops_window_ends_on_the_wall_clock(tiny_root, monkeypatch):
+    """The harness's own time between steps counts toward an ops window,
+    as it does toward a query window: a slow aside after each create ends
+    the window in fewer cycles. Every cycle is still digested, and
+    build_rows_per_s still divides the created rows by the summed step
+    time, the asides left out."""
+    seconds = 1.5
+    windows = []
+    real = harness.run_ops_window
+
+    def spy(*args, **kwargs):
+        windows.append(real(*args, **kwargs))
+        return windows[-1]
+
+    def slow_aside(cycle, step, ctx):
+        if step == "create":
+            time.sleep(seconds)
+
+    monkeypatch.setattr(harness, "run_ops_window", spy)
+    runs = [harness.run_cell(tiny_root, BUILD, SEED, seconds, False,
+                             allow_cpu=True, fault=f)
+            for f in (None, slow_aside)]
+    (plain, _, _), (slowed, s0, s1) = windows
+    assert len(slowed) == 1 < len(plain)
+    for res, (cycles, t0, t1) in zip(runs, windows):
+        assert res["correct"], res["checks"]
+        assert res["attempted"] == len(cycles)
+        assert t1 - t0 >= seconds
+        assert all("digest" in c.record for c in cycles)
+        rows = sum(c.record["source_rows"] for c in cycles)
+        assert res["metrics"]["build_rows_per_s"]["value"] == \
+            pytest.approx(rows / sum(c.step_s for c in cycles))
+    assert sum(c.step_s for c in slowed) < seconds <= s1 - s0
 
 
 def test_new_cell_from_new_files_only(tiny_root):
